@@ -22,13 +22,19 @@ Three passes, one CLI (``python -m repro.analysis``), one CI gate
   forms of :mod:`repro.core.worker_counts`.
 """
 from .intervals import Interval
-from .overflow import certified_bk, verify_field_pipeline, verify_spec_space
+from .overflow import (
+    certified_bk,
+    certified_limb_k,
+    verify_field_pipeline,
+    verify_spec_space,
+)
 from .report import Finding, load_baseline, write_baseline
 
 __all__ = [
     "Interval",
     "Finding",
     "certified_bk",
+    "certified_limb_k",
     "load_baseline",
     "verify_field_pipeline",
     "verify_spec_space",

@@ -9,6 +9,8 @@ container when its whole interval does:
 
 * ``fits_int64``        — ``−2⁶³ ≤ lo`` and ``hi < 2⁶³`` (the accumulator
   contract of :func:`repro.mpc.field.acc_window`);
+* ``fits_int32``        — ``−2³¹ ≤ lo`` and ``hi < 2³¹`` (the int8 limb
+  GEMM's MXU accumulator);
 * ``fits_uint64``       — ``0 ≤ lo`` and ``hi < 2⁶⁴`` (Montgomery REDC);
 * ``fits_f64_mantissa`` — ``|lo|, |hi| ≤ 2⁵³`` (float64 represents every
   integer up to 2⁵³ exactly: the limb-GEMM partial-sum contract).
@@ -23,6 +25,8 @@ import dataclasses
 
 INT64_MIN = -(2**63)
 INT64_MAX = 2**63 - 1
+INT32_MIN = -(2**31)
+INT32_MAX = 2**31 - 1
 UINT64_MAX = 2**64 - 1
 F64_EXACT = 2**53
 
@@ -102,6 +106,10 @@ class Interval:
     @property
     def fits_int64(self) -> bool:
         return INT64_MIN <= self.lo and self.hi <= INT64_MAX
+
+    @property
+    def fits_int32(self) -> bool:
+        return INT32_MIN <= self.lo and self.hi <= INT32_MAX
 
     @property
     def fits_uint64(self) -> bool:

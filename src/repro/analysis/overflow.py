@@ -5,10 +5,11 @@ executes — the Barrett multiply-shift fold (:mod:`repro.kernels.barrett`),
 the Pallas chunk-then-fold GEMM accumulator (:mod:`repro.kernels.
 modmatmul`), the single-window polyeval (:mod:`repro.kernels.polyeval`),
 the Karatsuba limb GEMM (:func:`repro.kernels.barrett.matmul_limbs`), the
+served int8 limb GEMM (:func:`repro.kernels.barrett.field_matmul`), the
 Montgomery REDC tables (:mod:`repro.mpc.montgomery`) and the decode/
 assemble partial-sum refolds — in the interval domain of
 :mod:`repro.analysis.intervals`, and proves no intermediate can leave its
-container (int64 / uint64 / exact-f64).  :func:`verify_spec_space` then
+container (int32 / int64 / uint64 / exact-f64).  :func:`verify_spec_space` then
 quantifies the proof over every ``(scheme, s, t, λ, m, bk)`` the autotuner
 can emit for a prime, so the ``acc_window`` contract is machine-checked
 for the whole reachable configuration space, not just the shapes tests
@@ -19,7 +20,8 @@ happened to run.
 :func:`repro.mpc.field.acc_window`), which is what makes the cross-check
 ``certified_bk(p) == acc_window(p)`` a proof rather than a tautology; the
 kernels consume the certified value (:func:`repro.kernels.modmatmul.
-_pick_blocks`).
+_pick_blocks`).  :func:`certified_limb_k` certifies the int32 window of
+the int8 limb GEMM the same way, and that GEMM chunks K by it.
 """
 from __future__ import annotations
 
@@ -63,12 +65,31 @@ def certified_bk(p: int) -> int:
         raise ValueError(f"need a modulus >= 2, got {p}")
     acc = Interval.residue(p)
     prod = Interval.residue(p) * Interval.residue(p)
+    return _largest_safe(lambda q: (acc + prod.sum_n(q)).fits_int64)
 
-    def safe(q: int) -> bool:
-        return (acc + prod.sum_n(q)).fits_int64
 
+@functools.lru_cache(maxsize=None)
+def certified_limb_k(p: int) -> int:
+    """Largest K chunk provably safe for the int8 limb GEMM's int32 sums.
+
+    Proof obligation of :func:`repro.kernels.barrett.field_matmul`: one
+    diagonal sum ``S_d = Σ_i A_i·B_{d−i}`` adds at most ``n_limbs(p)``
+    limb-pair products per K step, each limb in ``[0, 2⁷−1]``, inside one
+    int32 accumulator.  Derived by interval bisection; the GEMM chunks K
+    by this value.  ``33286`` for ``2²⁶ − 5``, ``26628`` for Mersenne-31.
+    """
+    from ..kernels.barrett import LIMB_BITS, n_limbs
+
+    limb = Interval(0, (1 << LIMB_BITS) - 1)
+    group = (limb * limb).sum_n(n_limbs(p))
+    return _largest_safe(lambda k: group.sum_n(k).fits_int32)
+
+
+def _largest_safe(safe) -> int:
+    """Largest ``q ≥ 1`` with ``safe(q)`` for a monotone predicate (1 if
+    none): doubling, then bisection."""
     if not safe(1):
-        return 1        # per-product fold regime (window <= 1)
+        return 1
     lo, hi = 1, 2
     while safe(hi):
         lo, hi = hi, hi * 2
@@ -181,6 +202,43 @@ def prove_limb_gemm(p: int, k: int) -> None:
     _require(final.fits_int64, "limb final fold leaves int64", final)
 
 
+def prove_int8_limb_gemm(p: int, k: int) -> None:
+    """The served field GEMM at inner dim ``k`` stays in int32 / int64.
+
+    Mirrors :func:`repro.kernels.barrett.field_matmul`: K cut into
+    ``chunks`` equal chunks of at most :func:`certified_limb_k`; per chunk
+    the diagonal sums fit int32; the chunk sums fit int64 and fold to
+    residues; the :func:`~repro.kernels.barrett.limb_schedule`
+    recombination never leaves int64 and ends in ``mod_p``'s domain.
+    """
+    from ..kernels.barrett import (
+        INT32_MAX, LIMB_BITS, limb_schedule, n_limbs)
+
+    if p.bit_length() > 31:
+        raise OverflowProofError(
+            f"limb recombination needs p < 2^31, got {p}")
+    if k < 1:
+        raise ValueError(f"need k >= 1, got {k}")
+    chunks = -(-k // certified_limb_k(p))
+    kc = -(-k // chunks)
+    limb = Interval(0, (1 << LIMB_BITS) - 1)
+    diag = (limb * limb).sum_n(n_limbs(p) * kc)
+    _require(diag.fits_int32,
+             f"limb GEMM diagonal sum leaves int32 at K-chunk {kc} (p={p})",
+             diag)
+    _require(diag.sum_n(chunks).fits_int64,
+             f"limb GEMM chunk sum leaves int64 at {chunks} chunks (p={p})",
+             diag.sum_n(chunks))
+    s = Interval(0, INT32_MAX if chunks == 1 else p - 1)
+    total = Interval.const(0)
+    for w, fold in limb_schedule(p, s.hi):
+        if fold:
+            total = Interval.residue(p)
+        total = total + s.scale(w)
+        _require(total.fits_int64,
+                 f"limb recombination leaves int64 (p={p}, K={k})", total)
+
+
 def prove_montgomery(p: int) -> None:
     """REDC never wraps uint64 and its output fits one subtract.
 
@@ -234,6 +292,8 @@ def verify_field_pipeline(p: int, *, bk: Optional[int] = None,
     prove_polyeval(p, k_poly if k_poly is not None else min(cert, 128))
     prove_limb_gemm(p, min(k_gemm, 1 << (53 - 2 * ((p.bit_length() + 1)
                                                    // 2) - 2)))
+    prove_int8_limb_gemm(p, k_gemm)
+    prove_int8_limb_gemm(p, certified_limb_k(p) + 1)   # the chunked path
     prove_montgomery(p)
     prove_assemble(p)
     return {"p": p, "certified_bk": cert, "verified_bk": eff_bk}
@@ -271,7 +331,7 @@ def verify_spec_space(p: int, *, max_m: int = 256,
     * exchange mix:                polyeval at ``K = N``,
     * phase-2 worker GEMM:         the ``bk = min(512, certified, m/s)``
       accumulator chain (plus the jnp refold at its chunk count),
-    * the limb-GEMM f64 path at the same inner dim,
+    * the served int8 limb GEMM at every one of those inner dims,
 
     routing any K beyond one window through the chunked-path obligation
     exactly as the kernels do.  Returns counting stats; raises
@@ -287,6 +347,7 @@ def verify_spec_space(p: int, *, max_m: int = 256,
         configs += 1
         for k_terms in (t * s + z, t * t + z + 2 * a, n):
             max_k_seen = max(max_k_seen, k_terms)
+            window_checks.add(("int8", k_terms, 0))
             if k_terms <= cert:
                 window_checks.add(("poly", k_terms, 1))
             else:       # kernels refuse; the chunked path serves this K
@@ -299,7 +360,7 @@ def verify_spec_space(p: int, *, max_m: int = 256,
             if k_inner >= 1:
                 bk = max(1, min(DEFAULT_BK, cert, k_inner))
                 window_checks.add(("chain", bk, -(-k_inner // bk)))
-                window_checks.add(("limb", k_inner, 0))
+                window_checks.add(("int8", k_inner, 0))
             lcm += step
     prove_barrett_fold(p)
     prove_montgomery(p)
@@ -310,7 +371,7 @@ def verify_spec_space(p: int, *, max_m: int = 256,
         elif kind == "chain":
             prove_acc_chain(p, kk, chunks)
         else:
-            prove_limb_gemm(p, kk)
+            prove_int8_limb_gemm(p, kk)
     return {"p": p, "configs": configs, "distinct_proofs":
             len(window_checks), "certified_bk": cert,
             "max_inner_dim": max_k_seen}

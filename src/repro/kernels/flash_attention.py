@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..mpc.errors import ShapeContractError
+from ..runtime import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -81,7 +82,7 @@ def flash_attention(
     scale: float | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: [B, T, Hq, D]; k, v: [B, S, Hkv, D]; Hq % Hkv == 0 → [B, T, Hq, D]."""
     b, tq, hq, d = q.shape
@@ -119,6 +120,6 @@ def flash_attention(
             pltpu.VMEM((bq_, 1), jnp.float32),   # running max
             pltpu.VMEM((bq_, 1), jnp.float32),   # running sum
         ],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(qt, kt, vt)
     return out[:, :, :tq].transpose(0, 2, 1, 3)

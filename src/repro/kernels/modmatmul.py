@@ -22,9 +22,10 @@ Two entry points:
   in ONE ``pallas_call``, the worker index as leading grid dimension; this
   is what ``AGECMPCProtocol.run(mode="pallas")`` uses for phase 2.
 
-Validated against :func:`repro.kernels.ref.modmatmul_ref` in interpret mode
-(this container is CPU-only; ``interpret=True`` executes the same block
-program).
+Validated against :func:`repro.kernels.ref.modmatmul_ref` in interpret mode.
+Mosaic refuses both entry points on TPU (``'tpu.matmul' op Expected matmul
+acc to be 32-bit``: the int64 accumulator), so ``mode="pallas"`` refuses to
+run there (:meth:`repro.mpc.protocol.AGECMPCProtocol.run`).
 """
 from __future__ import annotations
 
@@ -36,6 +37,7 @@ from jax.experimental import pallas as pl
 
 from ..mpc.errors import ShapeContractError
 from ..mpc.field import acc_window
+from ..runtime import pallas_interpret
 from .barrett import mod_p
 
 
@@ -115,7 +117,7 @@ def modmatmul(
     bm: int = 128,
     bn: int = 128,
     bk: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``(a @ b) mod p`` with explicit VMEM tiling.
 
@@ -142,7 +144,7 @@ def modmatmul(
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int64),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(a, b)
     return out[:m, :n]
 
@@ -158,7 +160,7 @@ def modmatmul_batched(
     bm: int = 128,
     bn: int = 128,
     bk: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``(a[w] @ b[w]) mod p`` for every worker ``w`` in ONE ``pallas_call``.
 
@@ -187,6 +189,6 @@ def modmatmul_batched(
         ],
         out_specs=pl.BlockSpec((1, bm_, bn_), lambda ww, i, j, kk: (ww, i, j)),
         out_shape=jax.ShapeDtypeStruct((w, mp, np_), jnp.int64),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(a, b)
     return out[:, :m, :n]

@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 
 from ..mpc.errors import ShapeContractError
 from ..mpc.field import acc_window
+from ..runtime import pallas_interpret
 from .barrett import mod_p
 
 
@@ -47,7 +48,7 @@ def polyeval(
     p: int,
     bn: int = 8,
     bc: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``vand: [N, K]`` (α powers), ``terms: [K, C]`` (flattened blocks).
 
@@ -78,6 +79,6 @@ def polyeval(
         ],
         out_specs=pl.BlockSpec((bn_, bc_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, cp), jnp.int64),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(vand, terms)
     return out[:n, :c]

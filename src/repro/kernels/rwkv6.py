@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..runtime import pallas_interpret
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref,
                 *, bt: int):
@@ -59,7 +61,7 @@ def rwkv6(
     u: jax.Array,
     *,
     bt: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """r,k,w: [B, T, H, K]; v: [B, T, H, V]; u: [H, K] → [B, T, H, V]."""
     b, t, h, dk = k.shape
@@ -87,7 +89,7 @@ def rwkv6(
         out_specs=pl.BlockSpec((1, bt_, dv), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, tp, dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(rf, kf, vf, wf, uf)
     out = out[:, :t].reshape(b, h, t, dv).transpose(0, 2, 1, 3)
     return out

@@ -693,6 +693,9 @@ class MPCSession:
             ta = tile_blocks(pa, block)          # [gr, gk, m, m]
             tb = tile_blocks(pb, block)          # [gk, gc, m, m]
             for i in range(tm.gr):
+                # one transposed A tile per (i, l), shared by every column
+                # block (a wide projection would otherwise copy it gc times)
+                a_row = [ta[i, l].T for l in range(tm.gk)]
                 for j in range(tm.gc):
                     for l in range(tm.gk):
                         # single-block calls consume the caller's key
@@ -700,7 +703,7 @@ class MPCSession:
                         bk = (base if n_ops == 1
                               else jax.random.fold_in(base, len(ops)))
                         ops.append(BlockOp(
-                            proto=proto, a=ta[i, l].T, b=tb[l, j],
+                            proto=proto, a=a_row[l], b=tb[l, j],
                             key=bk, survivors=eff))
 
         n_pieces = len(pieces)

@@ -175,7 +175,8 @@ class ShardedBackend(MPCBackend):
         return float(dispatch_waves(spec.n_workers,
                                     self.mesh.shape[self.axis]))
 
-    def _runner(self, proto):
+    def runner(self, proto):
+        """The ``ShardedCMPC`` runner serving ``proto``'s plan (one each)."""
         from .secure_matmul import ShardedCMPC
 
         key = proto.plan_key
@@ -190,7 +191,7 @@ class ShardedBackend(MPCBackend):
         outs: List[BlockResult] = []
         for op in ops:
             try:
-                outs.append(self._runner(op.proto).run(
+                outs.append(self.runner(op.proto).run(
                     op.a, op.b, op.key, survivors=op.survivors))
             except RuntimeError as e:
                 outs.append(BlockFailure(str(e)))

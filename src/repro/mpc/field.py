@@ -7,7 +7,8 @@ with headroom for *chunked accumulation*: ``(p−1)² < 2⁵²``, so up to
 (:mod:`repro.kernels.modmatmul`) is built around.
 
 ``p = 2³¹ − 1`` (Mersenne-31) is also supported for wider fixed-point
-headroom; its TPU-native path uses 8-bit limb MXU matmuls (see DESIGN.md §3).
+headroom.  Both primes multiply matrices through the 7-bit-limb int8 GEMM
+(:func:`repro.kernels.barrett.field_matmul`, DESIGN.md §3).
 
 All array ops are JAX (int64 via jax_enable_x64-free int32/int64 mixed mode:
 we store field elements as int64 arrays; jax defaults allow int64 creation
@@ -20,6 +21,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..kernels.barrett import field_matmul
 from .errors import InvariantError
 
 jax.config.update("jax_enable_x64", True)
@@ -111,34 +113,12 @@ class Field:
         return pow(a, self.p - 2, self.p)
 
     # ------------------------------------------------------------ mod matmul
-    def matmul(self, a, b, *, chunk: int | None = None):
-        """Exact ``(a @ b) mod p`` with chunk-then-fold accumulation.
+    def matmul(self, a, b):
+        """Exact ``(a @ b) mod p``: the int8 limb GEMM of the served path.
 
-        ``a: [..., M, K]``, ``b: [..., K, N]`` int64 field elements.
+        ``a: [..., M, K]``, ``b: [..., K, N]`` field elements in ``[0, p)``.
         """
-        window = chunk or acc_window(self.p)
-        a = jnp.asarray(a, jnp.int64)
-        b = jnp.asarray(b, jnp.int64)
-        k = a.shape[-1]
-        if window <= 1 or k <= window:
-            if window <= 1 and k > 1:
-                # per-product fold: reduce each outer product then sum mod p
-                return self._matmul_per_product(a, b)
-            return jnp.matmul(a, b) % self.p
-        # fold every `window` inner-dim elements
-        n_chunks = -(-k // window)
-        pad = n_chunks * window - k
-        if pad:
-            a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
-            b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, pad), (0, 0)])
-        a = a.reshape(*a.shape[:-1], n_chunks, window)
-        b = b.reshape(*b.shape[:-2], n_chunks, window, b.shape[-1])
-        partial_ = jnp.einsum("...mcw,...cwn->...cmn", a, b) % self.p
-        return jnp.sum(partial_, axis=-3) % self.p
-
-    def _matmul_per_product(self, a, b):
-        prods = (a[..., :, :, None] * b[..., None, :, :]) % self.p
-        return jnp.sum(prods, axis=-2) % self.p
+        return field_matmul(a, b, p=self.p)
 
     # ---------------------------------------------------------- fixed point
     @property

@@ -50,9 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.age import AGECode, GeneralizedPolyCode, optimal_age_code, polydot_code
-from ..kernels.barrett import matmul_folded, matmul_limbs, mod_p
+from ..kernels.barrett import field_matmul, mod_p
 from .errors import MaskShapeError
-from .field import Field, acc_window
+from .field import Field
 from .lagrange import (
     ALPHA_POOL_LIMIT,
     ALPHA_SEARCH_SEED,
@@ -121,9 +121,9 @@ class ProtocolStages:
       path (DESIGN.md §9); MAC parameters are traced arguments, so one
       compiled program serves every request key.
 
-    All stages share the plan's Barrett/limb ``mm`` dispatch, so every
-    path is bit-exact for any supported prime (window contract,
-    DESIGN.md §3).
+    Every GEMM in them is :func:`repro.kernels.barrett.field_matmul` (int8
+    limb dots with int32 accumulation), so every stage is bit-exact for
+    any supported prime and lowers on TPU (DESIGN.md §3).
     """
 
     encode: Callable
@@ -198,22 +198,15 @@ def _build_stages(plan: "ProtocolPlan") -> ProtocolStages:
     Bit-exactness matches the retired monolithic fused runner: phase-1
     secret draws replicate the reference path exactly; the phase-2 masks
     cancel identically in Y (``(V⁻¹V)[0:t², t²:t²+z] ≡ 0``), so the
-    aggregate mask is drawn directly from raw bits mod p.  Matmuls run
-    limb-decomposed over exact f64 GEMM where the K extent makes 3 GEMMs
-    cheaper than scalar int64 MACs, chunk-then-fold int64 otherwise.
+    aggregate mask is drawn directly from raw bits mod p.  Every matmul
+    is the int8 limb GEMM :func:`~repro.kernels.barrett.field_matmul`.
     """
     p, s, t, z, m = plan.p, plan.s, plan.t, plan.z, plan.m
     mt, ms = m // t, m // s
     n, t2z = plan.n_workers, plan.recovery_threshold
-    win = acc_window(p)
 
     def mm(x, y):
-        # crossover (measured, m=144/N=17): limb recombination costs ~10
-        # elementwise passes; the int64 dot costs K scalar-MAC passes.
-        # Only the phase-2 worker product (K = m/t) clears the bar.
-        if p.bit_length() <= 31 and x.shape[-1] > 32:
-            return matmul_limbs(x, y, p=p)
-        return matmul_folded(x, y, p=p, window=win)
+        return field_matmul(x, y, p=p)
 
     va = jnp.asarray(plan.vand_a)
     vb = jnp.asarray(plan.vand_b)
@@ -262,7 +255,7 @@ def _build_stages(plan: "ProtocolPlan") -> ProtocolStages:
 
     def tags(i_pts, gamma, offsets, rvec):
         # γ·⟨vec(I(α_n)), r⟩ + o_n mod p (DESIGN.md §9).  The compression
-        # dot runs through the shared mm dispatch (window-safe); the final
+        # dot is the shared field GEMM (K-chunked to its window); the final
         # γ·v + o fits int64 for any p < 2³¹·⁵: v, γ < p ⇒ γ·v < 2⁶².
         v = mm(jnp.asarray(i_pts, jnp.int64).reshape(n, mt * mt),
                rvec.reshape(mt * mt, 1))[:, 0]

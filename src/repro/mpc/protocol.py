@@ -25,8 +25,9 @@ only the decode stage's rows in from the plan's survivor-table LRU — no
 eager fallback.  ``mode="reference"`` keeps the original eager
 phase-by-phase path (the bit-exactness oracle and benchmark baseline);
 ``mode="pallas"`` routes the heavy phases through the Pallas kernels
-(:mod:`repro.kernels.modmatmul`, :mod:`repro.kernels.polyeval`) — interpret
-mode on CPU, the real tiled programs on TPU.
+(:mod:`repro.kernels.modmatmul`, :mod:`repro.kernels.polyeval`) in
+interpret mode; Mosaic refuses their int64 accumulators, so that mode
+raises on TPU.
 """
 from __future__ import annotations
 
@@ -40,10 +41,22 @@ import numpy as np
 
 from ..core.age import GeneralizedPolyCode
 from ..kernels.barrett import mod_p
+from .. import runtime
 from .api import MPCSpec
 from .field import DEFAULT_FIELD, Field, acc_window
 from .lagrange import inv_mod, vandermonde
 from .planner import PlanKey, ProtocolPlan
+
+
+def _refuse_pallas_on_tpu() -> None:
+    """The Pallas field kernels accumulate in int64, which Mosaic refuses
+    on TPU (``'tpu.matmul' op Expected matmul acc to be 32-bit``)."""
+    if runtime.on_tpu():
+        raise NotImplementedError(
+            "mode='pallas' cannot run on TPU: Mosaic refuses the modmatmul/"
+            "polyeval kernels' int64 accumulator ('tpu.matmul' op Expected "
+            "matmul acc to be 32-bit); use the default mode='fused', whose "
+            "int8 limb GEMM lowers on TPU")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,20 +217,18 @@ class AGECMPCProtocol:
         return f_a, f_b
 
     # -------------------------------------------------------------- phase 2
-    def phase2_compute(self, f_a, f_b, *, use_kernel: bool = False,
-                       interpret: Optional[bool] = None):
+    def phase2_compute(self, f_a, f_b, *, use_kernel: bool = False):
         """Each worker: H(α_n) = F_A(α_n)·F_B(α_n) mod p  (the hot loop).
 
         ``use_kernel=True`` routes through the batched Pallas kernel (all N
-        workers in one ``pallas_call``, worker index = grid dim 0);
-        ``interpret=None`` auto-selects interpret mode off-TPU."""
+        workers in one ``pallas_call``, worker index = grid dim 0; refused
+        on TPU, see :meth:`run`)."""
         if use_kernel:
             from ..kernels.modmatmul import modmatmul_batched
-            if interpret is None:
-                interpret = jax.default_backend() == "cpu"
+            _refuse_pallas_on_tpu()
             return modmatmul_batched(
                 jnp.asarray(f_a, jnp.int64), jnp.asarray(f_b, jnp.int64),
-                p=self.field.p, interpret=interpret)
+                p=self.field.p)
         return self.field.matmul(f_a, f_b)
 
     def phase2_exchange(self, h, key):
@@ -285,9 +296,10 @@ class AGECMPCProtocol:
           rows swapped in from the plan's LRU — the mask never changes
           which programs compile, only which rows they consume.  Exact for
           any supported prime (chunked to the field window).
-        * ``"pallas"`` — heavy phases through the Pallas kernels (interpret
-          mode on CPU; the tiled VMEM programs on TPU); survivor masks take
-          the same cached-rows decode.
+        * ``"pallas"`` — heavy phases through the Pallas kernels in
+          interpret mode; survivor masks take the same cached-rows decode.
+          Raises ``NotImplementedError`` on TPU, where Mosaic refuses the
+          kernels' int64 accumulators.
         * ``"reference"`` — the original eager phase-by-phase path, ending
           in the seed's per-call object-dtype survivor solve.
 
@@ -481,21 +493,17 @@ class AGECMPCProtocol:
                 "mode for small-window fields (DESIGN.md §3)")
 
     def _run_pallas(self, a, b, key, *,
-                    survivors: Optional[np.ndarray] = None,
-                    interpret: Optional[bool] = None):
+                    survivors: Optional[np.ndarray] = None):
         """Phases 1-3 through the Pallas kernels (bit-exact with ``run``).
 
-        ``interpret=None`` auto-selects: the compiled block programs on
-        TPU, interpret mode elsewhere (this container is CPU-only).  Same
-        window precondition as the reference path: the polyeval kernel
-        keeps K fully resident with one fold at the end.  Survivor masks
-        use the plan's cached decode tables, like the fused path.
+        Same window precondition as the reference path: the polyeval
+        kernel keeps K fully resident with one fold at the end.  Survivor
+        masks use the plan's cached decode tables, like the fused path.
         """
+        _refuse_pallas_on_tpu()
         self._require_window("mode='pallas' (single-fold polyeval)")
         from ..kernels.polyeval import polyeval
 
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
         dec_idx = self.survivor_prefix(survivors)
         dec_rows = self.plan.survivor_rows(tuple(dec_idx))
 
@@ -509,23 +517,19 @@ class AGECMPCProtocol:
         sec_b = self.field.random(kb, (z, ms, mt))
         terms_a = jnp.concatenate([self._split_a(a), sec_a]).reshape(-1, mt * ms)
         terms_b = jnp.concatenate([self._split_b(b), sec_b]).reshape(-1, ms * mt)
-        f_a = polyeval(jnp.asarray(self.vand_a), terms_a, p=p,
-                       interpret=interpret).reshape(n, mt, ms)
-        f_b = polyeval(jnp.asarray(self.vand_b), terms_b, p=p,
-                       interpret=interpret).reshape(n, ms, mt)
-        h = self.phase2_compute(f_a, f_b, use_kernel=True,
-                                interpret=interpret)
+        f_a = polyeval(jnp.asarray(self.vand_a), terms_a, p=p).reshape(n, mt, ms)
+        f_b = polyeval(jnp.asarray(self.vand_b), terms_b, p=p).reshape(n, ms, mt)
+        h = self.phase2_compute(f_a, f_b, use_kernel=True)
         r_mask = self.field.random(k2, (n, z, mt, mt))
         i_pts = polyeval(jnp.asarray(self.g_mix.T.copy()),
-                         h.reshape(n, mt * mt), p=p, interpret=interpret)
+                         h.reshape(n, mt * mt), p=p)
         mask_sum = mod_p(jnp.sum(r_mask, axis=0), p)
         i_pts = mod_p(
             i_pts + polyeval(jnp.asarray(self.vand_g_secret),
-                             mask_sum.reshape(z, mt * mt), p=p,
-                             interpret=interpret), p)
+                             mask_sum.reshape(z, mt * mt), p=p), p)
         y_blocks = polyeval(jnp.asarray(dec_rows),
                             i_pts[jnp.asarray(dec_idx)],
-                            p=p, interpret=interpret)
+                            p=p)
         grid = y_blocks.reshape(t, t, mt, mt)
         return grid.transpose(1, 2, 0, 3).reshape(m, m)
 

@@ -18,14 +18,16 @@ runner (DESIGN.md §3).
 from __future__ import annotations
 
 import dataclasses
+from functools import cached_property
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.compat import shard_map
+from ..kernels.barrett import field_matmul, mod_p
 from .api import MPCSpec
 from .field import Field
 from .protocol import AGECMPCProtocol
@@ -85,10 +87,10 @@ class ShardedCMPC:
 
     Optimization knobs (paper-faithful defaults; see EXPERIMENTS.md §Perf):
 
-    * ``wire_dtype``: "int64" (baseline) or "int32" — field elements fit 26
+    * ``wire_dtype``: "int64" (baseline) or "int32" — field elements fit 31
       bits; int32 halves argument/HBM/wire bytes.  The exchange then uses
-      :func:`mod_ring_reduce_scatter` (per-hop mod fold) instead of a plain
-      ``psum_scatter`` whose partial sums would overflow.
+      :func:`mod_ring_reduce_scatter` (per-hop mod fold) instead of one
+      ``psum_scatter`` of 16-bit halves.
     * ``prg_masks``: derive phase-2 masks R_w^{(n)} on-device from per-worker
       PRNG keys instead of shipping ~z·m²/t² scalars per worker from the
       host (PRG-based masking, standard MPC practice).
@@ -161,16 +163,15 @@ class ShardedCMPC:
 
         def step(terms_a, terms_b, masks):
             def local(vand_a, vand_b, g_mix, vand_g, ta, tb, mk):
+                nl = vand_a.shape[0]
                 # phase 1 (local workers' shares)
-                f_a = jnp.einsum("nk,krc->nrc", vand_a.astype(jnp.int64),
-                                 ta.astype(jnp.int64)) % p
-                f_b = jnp.einsum("nk,krc->nrc", vand_b.astype(jnp.int64),
-                                 tb.astype(jnp.int64)) % p
+                f_a = field_matmul(vand_a, ta.reshape(ta.shape[0], -1), p=p)
+                f_b = field_matmul(vand_b, tb.reshape(tb.shape[0], -1), p=p)
                 # phase 2 compute: H(α_n) = F_A·F_B
-                h = pr.field.matmul(f_a, f_b)
+                h = field_matmul(f_a.reshape((nl,) + ta.shape[1:]),
+                                 f_b.reshape((nl,) + tb.shape[1:]), p=p)
                 # phase 2 exchange: G contributions for every n', then scatter
-                g_all = jnp.einsum("nm,nrc->mrc", g_mix.astype(jnp.int64),
-                                   h) % p                           # [Np', ...]
+                g_all = field_matmul(g_mix.T, h.reshape(nl, -1), p=p)
                 if prg:
                     # derive local workers' masks from their keys on device:
                     # raw 64-bit stream mod p (bias 2⁻³⁸) — one generate pass
@@ -184,17 +185,25 @@ class ShardedCMPC:
                     mk_local = jax.vmap(mask_of)(mk)                # [nl,z,...]
                 else:
                     mk_local = mk.astype(jnp.int64)
-                g_all = (g_all + jnp.einsum(
-                    "mw,nwrc->mrc", vand_g.astype(jnp.int64),
-                    mk_local)) % p
+                # Σ_n Σ_w vand_g[n', w]·R_w^{(n)} = vand_g · (Σ_n R^{(n)})
+                mk_sum = mod_p(jnp.sum(mk_local, axis=0), p)      # [z, ...]
+                g_all = mod_p(g_all + field_matmul(
+                    vand_g, mk_sum.reshape(z, -1), p=p), p)
+                g_all = g_all.reshape((-1, mt, mt))                 # [Np', ...]
                 if wire == jnp.int32:
                     i_local = mod_ring_reduce_scatter(
                         g_all.astype(jnp.int32), axis, p, n_shards)
                     return i_local.astype(jnp.int64).reshape(
                         (-1,) + g_all.shape[1:])
-                i_local = jax.lax.psum_scatter(
-                    g_all, axis, scatter_dimension=0, tiled=True)
-                return i_local % p
+                # XLA:TPU has no int64 reduce-scatter: carry each residue
+                # (< 2³¹) as two 16-bit halves in int32 lanes, whose sums
+                # over ≤ 2¹⁵ devices cannot overflow, and rejoin them here
+                halves = jnp.stack([g_all & 0xFFFF, g_all >> 16],
+                                   axis=-1).astype(jnp.int32)
+                lo, hi = jnp.moveaxis(jax.lax.psum_scatter(
+                    halves, axis, scatter_dimension=0, tiled=True
+                ).astype(jnp.int64), -1, 0)
+                return mod_p(lo + (hi << 16), p)
 
             return shard_map(
                 local,
@@ -207,8 +216,20 @@ class ShardedCMPC:
 
         return jax.jit(step)
 
+    @cached_property
+    def _step(self):
+        """The compiled step, built once per runner (not per block)."""
+        return self.build_step()
+
     def run(self, a, b, key, *, survivors: Optional[np.ndarray] = None):
         """Full distributed run (phases 1-2 on mesh, decode on master)."""
+        pr = self.proto
+        i_pts = self.shares(a, b, key)
+        return pr.decode(np.asarray(i_pts)[: pr.n_workers], survivors)
+
+    def shares(self, a, b, key):
+        """Phases 1-2 on the mesh: the I points ``[N_pad, m/t, m/t]``,
+        sharded worker-major over ``axis``."""
         pr = self.proto
         k1a, k1b, k2 = jax.random.split(key, 3)
         sec_a = pr.field.random(
@@ -228,8 +249,7 @@ class ShardedCMPC:
         if self.wire_dtype == "int32":
             terms_a = terms_a.astype(jnp.int32)
             terms_b = terms_b.astype(jnp.int32)
-        i_pts = self.build_step()(terms_a, terms_b, masks)
-        return pr.decode(np.asarray(i_pts)[: pr.n_workers], survivors)
+        return self._step(terms_a, terms_b, masks)
 
 
 # ------------------------------------------------------------- float facade

@@ -234,11 +234,17 @@ def assemble(tm: TileMap, outs, p: int):
 
     ``outs``: one ``[m, m]`` field-domain array per block, ordered by
     :meth:`TileMap.block_index`.  The inner ``Σ_l`` folds mod p (adding
-    block products never changes the fixed-point scale).
+    block products never changes the fixed-point scale).  With one row
+    block, each output is cut to the ``r`` real rows before stacking: a
+    decode-shaped ``[1, D] × [D, V]`` product never materializes its
+    padded ``[m, gc·m]`` grid (2 GiB of int64 for a 128k vocabulary).
     """
-    stack = jnp.stack(outs).reshape(tm.gr, tm.gc, tm.gk, tm.m, tm.m)
+    rows = tm.r if tm.gr == 1 else tm.m
+    if rows < tm.m:
+        outs = [o[:rows] for o in outs]
+    stack = jnp.stack(outs).reshape(tm.gr, tm.gc, tm.gk, rows, tm.m)
     y = stack[:, :, 0]
     for l in range(1, tm.gk):
         y = (y + stack[:, :, l]) % p
-    full = y.transpose(0, 2, 1, 3).reshape(tm.gr * tm.m, tm.gc * tm.m)
+    full = y.transpose(0, 2, 1, 3).reshape(tm.gr * rows, tm.gc * tm.m)
     return full[: tm.r, : tm.c]

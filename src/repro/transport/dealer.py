@@ -15,8 +15,11 @@ serialize through one event stream.
 """
 from __future__ import annotations
 
+import os
 import queue
 import socket
+import subprocess
+import sys
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -104,9 +107,11 @@ class WorkerLink:
             pass
         proc = self._process
         if proc is not None:
-            proc.join(timeout=5.0)
-            if proc.is_alive():
+            try:
+                proc.wait(timeout=5.0)
+            except subprocess.TimeoutExpired:
                 proc.terminate()
+                proc.wait()
 
 
 def _plan_doc(spec, m: int, device: int) -> Dict:
@@ -153,22 +158,22 @@ class Dealer:
                                             delay_s=self.delay_s)
 
     def _spawn_processes(self, n: int) -> None:
-        import multiprocessing as mp
-
-        from .worker import process_worker
-
-        ctx = mp.get_context("spawn")
         listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         listener.bind(("127.0.0.1", 0))
         listener.listen(n)
         listener.settimeout(READY_TIMEOUT_S)
         host, port = listener.getsockname()
-        procs = []
-        for device in range(n):
-            proc = ctx.Process(target=process_worker,
-                               args=(host, port, device), daemon=True)
-            proc.start()
-            procs.append(proc)
+        # Each child stands for one edge device: it computes on its own CPU
+        # and never on the accelerator this process may hold (a chip admits
+        # one process), so JAX_PLATFORMS=cpu is in its environment before
+        # its interpreter imports JAX.
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(p for p in sys.path if p))
+        procs = [subprocess.Popen(
+            [sys.executable, "-c",
+             "from repro.transport.worker import process_worker; "
+             f"process_worker({host!r}, {port}, {device})"], env=env)
+            for device in range(n)]
         try:
             for _ in range(n):
                 sock, _addr = listener.accept()
@@ -181,6 +186,10 @@ class Dealer:
                 self.links[device] = WorkerLink(
                     device, sock, self.inbox, process=procs[device],
                     delay_s=self.delay_s)
+        except BaseException:
+            for proc in procs:
+                proc.kill()
+            raise
         finally:
             listener.close()
 

@@ -169,9 +169,10 @@ def worker_main(sock: socket.socket) -> None:
 def process_worker(host: str, port: int, device: int) -> None:
     """Entry point for ``spawn="process"`` workers.
 
-    Top-level so the multiprocessing ``spawn`` start method can pickle
-    it; connects back to the dealer's listener and identifies its slot
-    with a ``hello`` frame before entering :func:`worker_main`.
+    The dealer starts it in a fresh interpreter whose environment pins
+    JAX to the CPU; it connects back to the dealer's listener and
+    identifies its slot with a ``hello`` frame before entering
+    :func:`worker_main`.
     """
     sock = socket.create_connection((host, port), timeout=60.0)
     send_msg(sock, {"kind": "hello", "device": int(device),

@@ -58,6 +58,25 @@ def test_mutated_overwide_bk_rejected(p):
 
 
 @pytest.mark.parametrize("p", PRIMES)
+def test_certified_limb_k_is_the_int32_window(p):
+    """The limb GEMM's K chunk is the largest with n·K·127² in int32."""
+    from repro.kernels.barrett import n_limbs
+
+    n, k = n_limbs(p), overflow.certified_limb_k(p)
+    assert n * k * 127 ** 2 <= 2**31 - 1 < n * (k + 1) * 127 ** 2
+    assert (n, k) == {P_DEFAULT: (4, 33286), P_MERSENNE31: (5, 26628)}[p]
+
+
+@pytest.mark.parametrize("p", PRIMES)
+def test_int8_limb_gemm_proves_at_every_chunk_count(p):
+    cert = overflow.certified_limb_k(p)
+    for k in (1, 300, cert, cert + 1, 3 * cert + 7, 1 << 20):
+        overflow.prove_int8_limb_gemm(p, k)
+    with pytest.raises(overflow.OverflowProofError):
+        overflow.prove_int8_limb_gemm(2**31 + 11, 4)   # a 32-bit prime
+
+
+@pytest.mark.parametrize("p", PRIMES)
 def test_kernel_bit_exact_at_certified_corner(p):
     """Analyzer-vs-runtime agreement: all-(p−1) operands at the certified
     block are bit-exact against the reference — the exact corner the

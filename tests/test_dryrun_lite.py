@@ -16,9 +16,10 @@ SCRIPT = textwrap.dedent("""
     from repro.models.config import ShapeConfig
     from repro.launch.specs import build_cell
     from repro.launch.hlo_analysis import analyze
+    from repro.launch.mesh import make_mesh
     from repro.parallel.sharding import sharding_ctx
 
-    mesh = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 4), ("pod", "data", "model"))
     shapes = [ShapeConfig("t", 64, 8, "train"),
               ShapeConfig("p", 64, 8, "prefill"),
               ShapeConfig("d", 64, 8, "decode")]

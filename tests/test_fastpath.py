@@ -14,8 +14,10 @@ except ImportError:  # optional dep: deterministic fallback sweeps
     from _hypothesis_compat import strategies as st
 
 from repro.kernels import ref
+from repro.analysis.overflow import certified_limb_k
 from repro.kernels.barrett import (
     barrett_params,
+    field_matmul,
     matmul_folded,
     matmul_limbs,
     mod_p,
@@ -137,6 +139,52 @@ def test_matmul_limbs_exact_incl_worst_case(p):
     bw = np.full((k, 4), p - 1)
     got = np.asarray(matmul_limbs(aw, bw, p=p))
     np.testing.assert_array_equal(got, exact_matmul(aw, bw, p))
+
+
+# ------------------------------------------------------ int8 limb field GEMM
+
+
+@pytest.mark.parametrize("p", PRIMES)
+@pytest.mark.parametrize("shape", [(7, 300, 5), (1, 1, 1), (33, 65, 17),
+                                   (2, 9, 40)])
+def test_field_matmul_exact(p, shape):
+    m, k, n = shape
+    rng = np.random.default_rng(m + k + n)
+    a = rng.integers(0, p, (m, k))
+    b = rng.integers(0, p, (k, n))
+    got = np.asarray(field_matmul(a, b, p=p))
+    np.testing.assert_array_equal(got, exact_matmul(a, b, p))
+
+
+@pytest.mark.parametrize("p", PRIMES)
+def test_field_matmul_batched_and_broadcast(p):
+    rng = np.random.default_rng(5)
+    a = rng.integers(0, p, (3, 9, 40))
+    b = rng.integers(0, p, (3, 40, 11))
+    want = np.stack([exact_matmul(a[i], b[i], p) for i in range(3)])
+    np.testing.assert_array_equal(np.asarray(field_matmul(a, b, p=p)), want)
+    want = np.stack([exact_matmul(a[i], b[0], p) for i in range(3)])
+    np.testing.assert_array_equal(np.asarray(field_matmul(a, b[0], p=p)),
+                                  want)
+
+
+@pytest.mark.parametrize("p", PRIMES)
+@pytest.mark.parametrize("k", ["1", "257", "cert-1", "cert", "cert+1",
+                               "2cert+3"])
+def test_field_matmul_all_max_at_k_chunk_boundaries(p, k):
+    """All-(p−1) operands (the largest limbs, diagonal sums and carries)
+    on both sides of every K-chunk edge the int32 certificate sets, with
+    the Toeplitz stack on either operand."""
+    cert = certified_limb_k(p)
+    k = {"1": 1, "257": 257, "cert-1": cert - 1, "cert": cert,
+         "cert+1": cert + 1, "2cert+3": 2 * cert + 3}[k]
+    a = np.full((2, k), p - 1)
+    b = np.full((k, 3), p - 1)
+    np.testing.assert_array_equal(np.asarray(field_matmul(a, b, p=p)),
+                                  exact_matmul(a, b, p))
+    np.testing.assert_array_equal(np.asarray(field_matmul(b.T, a.T, p=p)),
+                                  exact_matmul(b.T, a.T, p))
+    assert int(field_matmul(a, b, p=p)[0, 0]) == k * (p - 1) ** 2 % p
 
 
 # ----------------------------------------------------------- batched kernel

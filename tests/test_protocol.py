@@ -162,12 +162,16 @@ def test_property_protocol_roundtrip(s, t, z, seed):
 
 
 def test_field_matmul_windows():
-    """chunk-then-fold matmul is exact vs object-dtype reference."""
+    """The field GEMM is exact vs the object-dtype reference on both sides
+    of its certified K-chunk window."""
+    from repro.analysis.overflow import certified_limb_k
+
     f = Field(P_DEFAULT)
     rng = np.random.default_rng(0)
-    a = rng.integers(0, f.p, (7, 300))
-    b = rng.integers(0, f.p, (300, 5))
-    want = np.array((a.astype(object) @ b.astype(object)) % f.p, np.int64)
-    for chunk in (1, 4, 64, 256, 4096):
-        got = np.asarray(f.matmul(a, b, chunk=chunk))
-        np.testing.assert_array_equal(got, want)
+    k_max = certified_limb_k(f.p)
+    for k in (1, 4, 300, k_max, k_max + 1):
+        a = rng.integers(0, f.p, (3, k))
+        b = rng.integers(0, f.p, (k, 2))
+        want = np.array((a.astype(object) @ b.astype(object)) % f.p,
+                        np.int64)
+        np.testing.assert_array_equal(np.asarray(f.matmul(a, b)), want)

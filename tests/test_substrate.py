@@ -124,7 +124,7 @@ def test_compressed_psum_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import shard_map
+        from jax import shard_map
         from repro.parallel.compressed import compressed_psum
 
         mesh = jax.make_mesh((4,), ("pod",))

@@ -346,6 +346,25 @@ def test_recorder_collects_wire_phase_samples():
         assert s.klass == spec.scheme
 
 
+def test_process_workers_never_see_the_accelerator(monkeypatch):
+    """spawn="process" children get JAX_PLATFORMS=cpu in their starting
+    environment: only the parent process may hold a chip."""
+    from repro.transport import dealer
+
+    seen = []
+
+    def fake_popen(argv, env):
+        seen.append(env)
+        raise OSError("not spawning in this test")
+
+    monkeypatch.setattr(dealer.subprocess, "Popen", fake_popen)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    proto = AGECMPCProtocol.from_spec(MPCSpec(s=2, t=2, z=1), m=4)
+    with pytest.raises(OSError, match="not spawning"):
+        dealer.Dealer(proto, spawn="process")
+    assert seen and seen[0]["JAX_PLATFORMS"] == "cpu"
+
+
 @pytest.mark.skipif(not os.environ.get("RUN_TRANSPORT_PROC"),
                     reason="process-spawn loopback is exercised by "
                            "examples/transport_demo.py (CI smoke); set "
