@@ -1,0 +1,3 @@
+"""device.idle_share.prefill: percent of the traced window in which no
+operation ran on the device, mean over the cell's chips (prefill cells)."""
+from bench.harness.readers import idle_share as read  # noqa: F401
