@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -184,41 +185,50 @@ def field_matmul(a, b, *, p: int):
     :func:`repro.analysis.overflow.certified_limb_k` — the interval
     certificate that ``n·K·127²`` fits int32 — and the ``S_d`` are
     recombined with int64 multiply-adds and :func:`mod_p` folds placed by
-    :func:`limb_schedule`.
+    :func:`limb_schedule`.  The ops sit under the named scope
+    ``field_gemm``, split into ``field_gemm.split`` (limbs),
+    ``field_gemm.dot`` (the int8 dot) and ``field_gemm.recombine``.
     """
     # lazy: repro.analysis.overflow imports this module (as _pick_blocks)
     from ..analysis.overflow import certified_limb_k
 
     if p.bit_length() > 31:
         raise ValueError(f"limb recombination needs p < 2^31, got {p}")
-    # residues < 2³¹: split them in native 32-bit lanes, not emulated int64
-    a = jnp.asarray(a).astype(jnp.int32)
-    b = jnp.asarray(b).astype(jnp.int32)
-    k = a.shape[-1]
-    chunks = max(1, -(-k // certified_limb_k(p)))
-    kc = -(-k // chunks)
-    pad = chunks * kc - k
-    if pad:
-        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
-        b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, pad), (0, 0)])
-    la = _limbs(a.reshape(*a.shape[:-1], chunks, kc), n_limbs(p))
-    lb = _limbs(b.reshape(*b.shape[:-2], chunks, kc, b.shape[-1]),
-                n_limbs(p))
-    if a.size <= b.size:
-        s = jnp.einsum("di...mck,i...ckn->dc...mn", _toeplitz(la), lb,
-                       preferred_element_type=jnp.int32)
-    else:
-        s = jnp.einsum("i...mck,di...ckn->dc...mn", la, _toeplitz(lb),
-                       preferred_element_type=jnp.int32)
-    s = s.astype(jnp.int64)                     # [2n−1, chunks, ..., M, N]
-    if chunks == 1:
-        s, s_max = s[:, 0], INT32_MAX
-    else:
-        s, s_max = mod_p(jnp.sum(s, axis=1), p), p - 1
-    out = s[0]                                  # w_0 = 1, never a fold
-    for d, (w, fold) in enumerate(limb_schedule(p, s_max)[1:], 1):
-        out = (mod_p(out, p) if fold else out) + s[d] * w
-    return mod_p(out, p)
+    with jax.named_scope("field_gemm"):
+        with jax.named_scope("field_gemm.split"):
+            # residues < 2³¹: split them in native 32-bit lanes, not
+            # emulated int64
+            a = jnp.asarray(a).astype(jnp.int32)
+            b = jnp.asarray(b).astype(jnp.int32)
+            k = a.shape[-1]
+            chunks = max(1, -(-k // certified_limb_k(p)))
+            kc = -(-k // chunks)
+            pad = chunks * kc - k
+            if pad:
+                a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+                b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, pad), (0, 0)])
+            la = _limbs(a.reshape(*a.shape[:-1], chunks, kc), n_limbs(p))
+            lb = _limbs(b.reshape(*b.shape[:-2], chunks, kc, b.shape[-1]),
+                        n_limbs(p))
+            small_a = a.size <= b.size
+            if small_a:
+                la = _toeplitz(la)
+            else:
+                lb = _toeplitz(lb)
+        with jax.named_scope("field_gemm.dot"):
+            s = jnp.einsum("di...mck,i...ckn->dc...mn" if small_a
+                           else "i...mck,di...ckn->dc...mn", la, lb,
+                           preferred_element_type=jnp.int32)
+        with jax.named_scope("field_gemm.recombine"):
+            s = s.astype(jnp.int64)             # [2n−1, chunks, ..., M, N]
+            if chunks == 1:
+                s, s_max = s[:, 0], INT32_MAX
+            else:
+                s, s_max = mod_p(jnp.sum(s, axis=1), p), p - 1
+            out = s[0]                          # w_0 = 1, never a fold
+            for d, (w, fold) in enumerate(limb_schedule(p, s_max)[1:], 1):
+                out = (mod_p(out, p) if fold else out) + s[d] * w
+            return mod_p(out, p)
 
 
 def matmul_folded(a, b, *, p: int, window: int):

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import span
 from .errors import MaskShapeError, QuorumError
 from .field import DEFAULT_FIELD, Field
 from .planner import PlanKey, ProtocolPlan, _resolve_code, get_plan
@@ -397,7 +398,8 @@ class MPCSession:
         self.stats = {"matmuls": 0, "blocks": 0, "flushes": 0,
                       "retiles": 0, "masks_dropped": 0,
                       "corrections": 0, "evicted_devices": 0,
-                      "waves": 0, "padded_lanes": 0, "deferred_groups": 0}
+                      "waves": 0, "padded_lanes": 0, "deferred_groups": 0,
+                      "host_bytes": 0}
 
     # ------------------------------------------------------------- helpers
     def validate_survivors(self, survivors) -> np.ndarray:
@@ -431,9 +433,10 @@ class MPCSession:
         spares/retune/replan escalation engages identically to a crash.
         """
         sched = getattr(self.backend, "scheduler_stats", None)
-        if sched is not None:  # wave-admission counters (DESIGN.md §10)
+        if sched is not None:  # waves (DESIGN.md §10), bytes via the host
             s = sched()
-            for k in ("waves", "padded_lanes", "deferred_groups"):
+            for k in ("waves", "padded_lanes", "deferred_groups",
+                      "host_bytes"):
                 self.stats[k] = int(s.get(k, 0))
         counters = getattr(self.backend, "byzantine_stats", None)
         if counters is None:
@@ -496,8 +499,9 @@ class MPCSession:
                survivors: Optional[np.ndarray] = None,
                encoded: bool = False, m: Optional[int] = None) -> int:
         """Queue one matmul; returns its request id (serve via :meth:`flush`)."""
-        req = self._build_request(a, b, key=key, survivors=survivors,
-                                  encoded=encoded, m=m)
+        with span("session.submit", rid=self._next_rid):
+            req = self._build_request(a, b, key=key, survivors=survivors,
+                                      encoded=encoded, m=m)
         self._pending.append(req)
         return req.rid
 
@@ -524,23 +528,27 @@ class MPCSession:
         ops: List[BlockOp] = []
         for req in queue:
             ops.extend(req.ops)
-        outs = []
-        if ops:
-            outs = self.backend.run_blocks(self._serve_ops(ops))
-            self.stats["flushes"] += 1   # one backend dispatch round
-            self._absorb_byzantine()
+        with span("session.flush", requests=len(queue), blocks=len(ops)):
+            outs = []
+            if ops:
+                with span("backend.run_blocks"):
+                    outs = self.backend.run_blocks(self._serve_ops(ops))
+                self.stats["flushes"] += 1   # one backend dispatch round
+                self._absorb_byzantine()
 
-        results: Dict[int, jnp.ndarray] = {}
-        pos = 0
-        for req in queue:
-            chunk = outs[pos: pos + len(req.ops)]
-            pos += len(req.ops)
-            bad = next((o for o in chunk if isinstance(o, BlockFailure)), None)
-            if bad is not None:
-                self.failures[req.rid] = bad.reason
-                continue
-            results[req.rid] = req.build(chunk)
-        return results
+            results: Dict[int, jnp.ndarray] = {}
+            pos = 0
+            for req in queue:
+                chunk = outs[pos: pos + len(req.ops)]
+                pos += len(req.ops)
+                bad = next((o for o in chunk if isinstance(o, BlockFailure)),
+                           None)
+                if bad is not None:
+                    self.failures[req.rid] = bad.reason
+                    continue
+                with span("session.assemble", rid=req.rid):
+                    results[req.rid] = req.build(chunk)
+            return results
 
     # ------------------------------------------------------- replan drain
     def _maybe_retile(self) -> None:
@@ -612,10 +620,11 @@ class MPCSession:
         out_dtype = jnp.result_type(a.dtype, b.dtype)
         if not jnp.issubdtype(out_dtype, jnp.floating):
             out_dtype = jnp.float64
-        ea = a if encoded else f.encode(a)
-        eb = b if encoded else f.encode(b)
-        ea = jnp.asarray(ea, jnp.int64) % f.p
-        eb = jnp.asarray(eb, jnp.int64) % f.p
+        with span("session.encode"):
+            ea = a if encoded else f.encode(a)
+            eb = b if encoded else f.encode(b)
+            ea = jnp.asarray(ea, jnp.int64) % f.p
+            eb = jnp.asarray(eb, jnp.int64) % f.p
 
         kdim = a.shape[-1]
         if b.ndim == 2:
@@ -685,26 +694,28 @@ class MPCSession:
         # the facade collapses to one protocol call on the operands
         clean = n_ops == 1 and (r, kdim, c) == (block, block, block)
         ops: List[BlockOp] = []
-        for pa, pb in pieces:
-            if clean:
-                ops.append(BlockOp(proto=proto, a=pa.T, b=pb, key=base,
-                                   survivors=eff))
-                continue
-            ta = tile_blocks(pa, block)          # [gr, gk, m, m]
-            tb = tile_blocks(pb, block)          # [gk, gc, m, m]
-            for i in range(tm.gr):
-                # one transposed A tile per (i, l), shared by every column
-                # block (a wide projection would otherwise copy it gc times)
-                a_row = [ta[i, l].T for l in range(tm.gk)]
-                for j in range(tm.gc):
-                    for l in range(tm.gk):
-                        # single-block calls consume the caller's key
-                        # directly: bit-identical to protocol.run
-                        bk = (base if n_ops == 1
-                              else jax.random.fold_in(base, len(ops)))
-                        ops.append(BlockOp(
-                            proto=proto, a=a_row[l], b=tb[l, j],
-                            key=bk, survivors=eff))
+        with span("session.tile"):
+            for pa, pb in pieces:
+                if clean:
+                    ops.append(BlockOp(proto=proto, a=pa.T, b=pb, key=base,
+                                       survivors=eff))
+                    continue
+                ta = tile_blocks(pa, block)          # [gr, gk, m, m]
+                tb = tile_blocks(pb, block)          # [gk, gc, m, m]
+                for i in range(tm.gr):
+                    # one transposed A tile per (i, l), shared by every
+                    # column block (a wide projection would otherwise copy
+                    # it gc times)
+                    a_row = [ta[i, l].T for l in range(tm.gk)]
+                    for j in range(tm.gc):
+                        for l in range(tm.gk):
+                            # single-block calls consume the caller's key
+                            # directly: bit-identical to protocol.run
+                            bk = (base if n_ops == 1
+                                  else jax.random.fold_in(base, len(ops)))
+                            ops.append(BlockOp(
+                                proto=proto, a=a_row[l], b=tb[l, j],
+                                key=bk, survivors=eff))
 
         n_pieces = len(pieces)
 

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Sequence, Union
 
+from ..runtime import span
 from .api import BlockFailure, BlockOp
 
 BlockResult = Union[Any, BlockFailure]  # a field-domain array, or a failure
@@ -73,11 +74,13 @@ class MPCBackend:
         return {"corrections": 0, "evicted_devices": 0}
 
     def scheduler_stats(self) -> Dict[str, int]:
-        """Cumulative wave-admission counters (DESIGN.md §10): serving
-        waves dispatched, padded lanes burned, degraded groups deferred
-        behind healthy traffic.  Backends without wave machinery report
-        zeros."""
-        return {"waves": 0, "padded_lanes": 0, "deferred_groups": 0}
+        """Cumulative dispatch counters: serving waves dispatched, padded
+        lanes burned and degraded groups deferred behind healthy traffic
+        (wave admission, DESIGN.md §10), and ``host_bytes``, the bytes of
+        shares brought to the host and sent back between the coded phases.
+        Backends without such machinery report zeros."""
+        return {"waves": 0, "padded_lanes": 0, "deferred_groups": 0,
+                "host_bytes": 0}
 
     def take_new_liars(self) -> set:
         """Drain liar ids caught since the last call — roster device ids
@@ -134,14 +137,15 @@ class LocalBackend(MPCBackend):
 
     def run_blocks(self, ops: Sequence[BlockOp]) -> List[BlockResult]:
         outs: List[BlockResult] = []
-        for op in ops:
+        for i, op in enumerate(ops):
             try:
-                if op.proto.adversaries:
-                    outs.append(self._run_verified(op))
-                else:
-                    outs.append(op.proto.run(op.a, op.b, op.key,
-                                             survivors=op.survivors,
-                                             mode=self.mode))
+                with span("block", block=i):
+                    if op.proto.adversaries:
+                        outs.append(self._run_verified(op))
+                    else:
+                        outs.append(op.proto.run(op.a, op.b, op.key,
+                                                 survivors=op.survivors,
+                                                 mode=self.mode))
             except RuntimeError as e:  # below-threshold mask / liar
                 outs.append(BlockFailure(str(e)))  # budget blown: isolate
         return outs
@@ -187,12 +191,17 @@ class ShardedBackend(MPCBackend):
                 prg_masks=self.prg_masks)
         return sh
 
+    def scheduler_stats(self) -> Dict[str, int]:
+        return {**super().scheduler_stats(), "host_bytes": sum(
+            r.counters["host_bytes"] for r in self._runners.values())}
+
     def run_blocks(self, ops: Sequence[BlockOp]) -> List[BlockResult]:
         outs: List[BlockResult] = []
-        for op in ops:
+        for i, op in enumerate(ops):
             try:
-                outs.append(self.runner(op.proto).run(
-                    op.a, op.b, op.key, survivors=op.survivors))
+                with span("block", block=i):
+                    outs.append(self.runner(op.proto).run(
+                        op.a, op.b, op.key, survivors=op.survivors))
             except RuntimeError as e:
                 outs.append(BlockFailure(str(e)))
         return outs
@@ -235,7 +244,8 @@ class BatchedBackend(MPCBackend):
 
     def scheduler_stats(self) -> Dict[str, int]:
         s = self.engine.stats
-        return {"waves": s["waves"], "padded_lanes": s["padded_lanes"],
+        return {**super().scheduler_stats(), "waves": s["waves"],
+                "padded_lanes": s["padded_lanes"],
                 "deferred_groups": s["deferred_groups"]}
 
     def take_new_liars(self) -> set:

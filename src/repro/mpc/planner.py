@@ -200,6 +200,8 @@ def _build_stages(plan: "ProtocolPlan") -> ProtocolStages:
     cancel identically in Y (``(V⁻¹V)[0:t², t²:t²+z] ≡ 0``), so the
     aggregate mask is drawn directly from raw bits mod p.  Every matmul
     is the int8 limb GEMM :func:`~repro.kernels.barrett.field_matmul`.
+    Each stage body runs under the named scope ``mpc.<stage>``, so the
+    device ops of ``front`` and ``fused`` carry their stage's name.
     """
     p, s, t, z, m = plan.p, plan.s, plan.t, plan.z, plan.m
     mt, ms = m // t, m // s
@@ -216,35 +218,39 @@ def _build_stages(plan: "ProtocolPlan") -> ProtocolStages:
     default_idx = jnp.arange(t2z)
 
     def encode(a, b, k1):
-        ka, kb = jax.random.split(k1)
-        sec_a = jax.random.randint(ka, (z, mt, ms), 0, p, dtype=jnp.int64)
-        sec_b = jax.random.randint(kb, (z, ms, mt), 0, p, dtype=jnp.int64)
-        at = a.T.reshape(t, mt, s, ms).transpose(0, 2, 1, 3)
-        blocks_a = at.reshape(t * s, mt, ms)
-        blocks_b = b.reshape(s, ms, t, mt).transpose(0, 2, 1, 3).reshape(
-            s * t, ms, mt)
-        terms_a = jnp.concatenate([blocks_a, sec_a]).reshape(-1, mt * ms)
-        terms_b = jnp.concatenate([blocks_b, sec_b]).reshape(-1, ms * mt)
-        f_a = mm(va, terms_a).reshape(n, mt, ms)
-        f_b = mm(vb, terms_b).reshape(n, ms, mt)
-        return f_a, f_b
+        with jax.named_scope("mpc.encode"):
+            ka, kb = jax.random.split(k1)
+            sec_a = jax.random.randint(ka, (z, mt, ms), 0, p, dtype=jnp.int64)
+            sec_b = jax.random.randint(kb, (z, ms, mt), 0, p, dtype=jnp.int64)
+            at = a.T.reshape(t, mt, s, ms).transpose(0, 2, 1, 3)
+            blocks_a = at.reshape(t * s, mt, ms)
+            blocks_b = b.reshape(s, ms, t, mt).transpose(0, 2, 1, 3).reshape(
+                s * t, ms, mt)
+            terms_a = jnp.concatenate([blocks_a, sec_a]).reshape(-1, mt * ms)
+            terms_b = jnp.concatenate([blocks_b, sec_b]).reshape(-1, ms * mt)
+            f_a = mm(va, terms_a).reshape(n, mt, ms)
+            f_b = mm(vb, terms_b).reshape(n, ms, mt)
+            return f_a, f_b
 
     def worker_compute(f_a, f_b):
-        return mm(f_a, f_b)                                   # [n, mt, mt]
+        with jax.named_scope("mpc.worker_compute"):
+            return mm(f_a, f_b)                               # [n, mt, mt]
 
     def exchange(h, k2):
-        mask_sum = (jax.random.bits(k2, (z, mt, mt), jnp.uint64)
-                    % jnp.uint64(p)).astype(jnp.int64)
-        i_pts = mm(gm_t, h.reshape(n, mt * mt))
-        i_pts = mod_p(i_pts + mm(vg, mask_sum.reshape(z, mt * mt)), p)
-        return i_pts.reshape(n, mt, mt)
+        with jax.named_scope("mpc.exchange"):
+            mask_sum = (jax.random.bits(k2, (z, mt, mt), jnp.uint64)
+                        % jnp.uint64(p)).astype(jnp.int64)
+            i_pts = mm(gm_t, h.reshape(n, mt * mt))
+            i_pts = mod_p(i_pts + mm(vg, mask_sum.reshape(z, mt * mt)), p)
+            return i_pts.reshape(n, mt, mt)
 
     def decode(i_pts, idx, rows):
-        i_sel = jnp.take(jnp.asarray(i_pts, jnp.int64), idx, axis=0)
-        y_blocks = mm(jnp.asarray(rows, jnp.int64),
-                      i_sel.reshape(t2z, mt * mt))
-        grid = y_blocks.reshape(t, t, mt, mt)                 # [l, i, r, c]
-        return grid.transpose(1, 2, 0, 3).reshape(m, m)
+        with jax.named_scope("mpc.decode"):
+            i_sel = jnp.take(jnp.asarray(i_pts, jnp.int64), idx, axis=0)
+            y_blocks = mm(jnp.asarray(rows, jnp.int64),
+                          i_sel.reshape(t2z, mt * mt))
+            grid = y_blocks.reshape(t, t, mt, mt)             # [l, i, r, c]
+            return grid.transpose(1, 2, 0, 3).reshape(m, m)
 
     def front(a, b, key):
         k1, k2 = jax.random.split(key)
@@ -257,9 +263,10 @@ def _build_stages(plan: "ProtocolPlan") -> ProtocolStages:
         # γ·⟨vec(I(α_n)), r⟩ + o_n mod p (DESIGN.md §9).  The compression
         # dot is the shared field GEMM (K-chunked to its window); the final
         # γ·v + o fits int64 for any p < 2³¹·⁵: v, γ < p ⇒ γ·v < 2⁶².
-        v = mm(jnp.asarray(i_pts, jnp.int64).reshape(n, mt * mt),
-               rvec.reshape(mt * mt, 1))[:, 0]
-        return (gamma * v + offsets) % p
+        with jax.named_scope("mpc.tags"):
+            v = mm(jnp.asarray(i_pts, jnp.int64).reshape(n, mt * mt),
+                   rvec.reshape(mt * mt, 1))[:, 0]
+            return (gamma * v + offsets) % p
 
     return ProtocolStages(
         encode=jax.jit(encode), worker_compute=jax.jit(worker_compute),

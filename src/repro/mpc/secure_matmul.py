@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import cached_property
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..kernels.barrett import field_matmul, mod_p
+from ..runtime import span
 from .api import MPCSpec
 from .field import Field
 from .protocol import AGECMPCProtocol
@@ -101,6 +102,10 @@ class ShardedCMPC:
     axis: str = "model"
     wire_dtype: str = "int64"
     prg_masks: bool = False
+    #: bytes through the host, counted by :meth:`run`
+    counters: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: {"host_bytes": 0}, init=False, compare=False,
+        repr=False)
 
     @classmethod
     def from_spec(cls, spec: MPCSpec, mesh: Mesh, *, axis: str = "model",
@@ -162,14 +167,8 @@ class ShardedCMPC:
             c = {k: v.astype(jnp.int32) for k, v in c.items()}
 
         def step(terms_a, terms_b, masks):
-            def local(vand_a, vand_b, g_mix, vand_g, ta, tb, mk):
-                nl = vand_a.shape[0]
-                # phase 1 (local workers' shares)
-                f_a = field_matmul(vand_a, ta.reshape(ta.shape[0], -1), p=p)
-                f_b = field_matmul(vand_b, tb.reshape(tb.shape[0], -1), p=p)
-                # phase 2 compute: H(α_n) = F_A·F_B
-                h = field_matmul(f_a.reshape((nl,) + ta.shape[1:]),
-                                 f_b.reshape((nl,) + tb.shape[1:]), p=p)
+            def exchange(g_mix, vand_g, h, mk):
+                nl = h.shape[0]
                 # phase 2 exchange: G contributions for every n', then scatter
                 g_all = field_matmul(g_mix.T, h.reshape(nl, -1), p=p)
                 if prg:
@@ -205,6 +204,21 @@ class ShardedCMPC:
                 ).astype(jnp.int64), -1, 0)
                 return mod_p(lo + (hi << 16), p)
 
+            def local(vand_a, vand_b, g_mix, vand_g, ta, tb, mk):
+                nl = vand_a.shape[0]
+                # phase 1 (local workers' shares)
+                with jax.named_scope("mpc.encode"):
+                    f_a = field_matmul(vand_a, ta.reshape(ta.shape[0], -1),
+                                       p=p)
+                    f_b = field_matmul(vand_b, tb.reshape(tb.shape[0], -1),
+                                       p=p)
+                # phase 2 compute: H(α_n) = F_A·F_B
+                with jax.named_scope("mpc.worker_compute"):
+                    h = field_matmul(f_a.reshape((nl,) + ta.shape[1:]),
+                                     f_b.reshape((nl,) + tb.shape[1:]), p=p)
+                with jax.named_scope("mpc.exchange"):
+                    return exchange(g_mix, vand_g, h, mk)
+
             return shard_map(
                 local,
                 mesh=self.mesh,
@@ -222,10 +236,22 @@ class ShardedCMPC:
         return self.build_step()
 
     def run(self, a, b, key, *, survivors: Optional[np.ndarray] = None):
-        """Full distributed run (phases 1-2 on mesh, decode on master)."""
+        """Full distributed run (phases 1-2 on mesh, decode on master).
+
+        The I points come to the host and their first N rows go back to
+        the default device for the decode; both transfers add to
+        ``counters["host_bytes"]``."""
         pr = self.proto
-        i_pts = self.shares(a, b, key)
-        return pr.decode(np.asarray(i_pts)[: pr.n_workers], survivors)
+        with span("sharded.shares"):
+            i_pts = self.shares(a, b, key)
+        with span("sharded.fetch"):
+            host = np.asarray(i_pts)
+            self.counters["host_bytes"] += host.nbytes
+        with span("sharded.upload"):
+            rows = jnp.asarray(host[: pr.n_workers])
+            self.counters["host_bytes"] += rows.nbytes
+        with span("sharded.decode"):
+            return pr.decode(rows, survivors)
 
     def shares(self, a, b, key):
         """Phases 1-2 on the mesh: the I points ``[N_pad, m/t, m/t]``,

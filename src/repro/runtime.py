@@ -1,4 +1,5 @@
-"""Where the program runs: the one platform check and the compile cache.
+"""Where the program runs: the one platform check, the compile cache and
+the program's host spans.
 
 * :func:`on_tpu` is the single place that asks which backend JAX drives.
   Pallas interpret mode (:func:`pallas_interpret`) and the ``mode="pallas"``
@@ -9,6 +10,12 @@
   ``JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads it itself) and
   at ``<checkout>/.jax_cache`` otherwise — a fixed path, since the path is
   part of the cache key.
+* :func:`span` is the one way the program marks a host span: a
+  ``jax.profiler.TraceAnnotation`` named ``mpc.<name>``.  It is recorded
+  only while a profiler session runs (``jax.profiler.start_trace``), on
+  the device trace's clock, and otherwise costs one constructor call.
+  Device work is marked with ``jax.named_scope`` inside the jitted
+  programs instead: metadata of the compiled ops, free at run time.
 """
 from __future__ import annotations
 
@@ -34,3 +41,9 @@ def use_compile_cache(checkout: str) -> str:
         path = os.path.join(os.path.abspath(checkout), ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def span(name: str, **ids: int) -> jax.profiler.TraceAnnotation:
+    """A host span ``mpc.<name>`` carrying the ids of its request
+    (``rid``, ``block``) as trace metadata."""
+    return jax.profiler.TraceAnnotation("mpc." + name, **ids)
