@@ -242,6 +242,8 @@ def four_chips(args, jax):
         report("sharded", p=p, shapes=[[M, M], [M, M]],
                mesh=[int(d.id) for d in mesh.devices.flat],
                shard_devices=sorted(placed), compile_s=c_s, run_s=r_s,
+               host_bytes=sess.stats["host_bytes"],
+               mesh_bytes=sess.stats["mesh_bytes"],
                check="bit-identical to the one-chip local result", ok=True)
 
 

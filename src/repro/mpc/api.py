@@ -399,7 +399,7 @@ class MPCSession:
                       "retiles": 0, "masks_dropped": 0,
                       "corrections": 0, "evicted_devices": 0,
                       "waves": 0, "padded_lanes": 0, "deferred_groups": 0,
-                      "host_bytes": 0}
+                      "host_bytes": 0, "mesh_bytes": 0}
 
     # ------------------------------------------------------------- helpers
     def validate_survivors(self, survivors) -> np.ndarray:
@@ -436,7 +436,7 @@ class MPCSession:
         if sched is not None:  # waves (DESIGN.md §10), bytes via the host
             s = sched()
             for k in ("waves", "padded_lanes", "deferred_groups",
-                      "host_bytes"):
+                      "host_bytes", "mesh_bytes"):
                 self.stats[k] = int(s.get(k, 0))
         counters = getattr(self.backend, "byzantine_stats", None)
         if counters is None:

@@ -10,7 +10,8 @@ field-domain result — or a ``BlockFailure`` — per op, in order:
   programs.
 * :class:`ShardedBackend` — the mesh runner
   (:class:`repro.mpc.secure_matmul.ShardedCMPC`): phases 1–2 shard over a
-  named axis with the exchange as one ``psum_scatter``; runner instances
+  named axis with the exchange as one ``psum_scatter``, and the decode
+  quorum's rows go chip to chip to the decode device; runner instances
   are cached per plan key.
 * :class:`BatchedBackend` — the grouping/vmap machinery of
   :class:`repro.mpc.engine.MPCEngine`: the whole op list is submitted and
@@ -76,11 +77,13 @@ class MPCBackend:
     def scheduler_stats(self) -> Dict[str, int]:
         """Cumulative dispatch counters: serving waves dispatched, padded
         lanes burned and degraded groups deferred behind healthy traffic
-        (wave admission, DESIGN.md §10), and ``host_bytes``, the bytes of
-        shares brought to the host and sent back between the coded phases.
-        Backends without such machinery report zeros."""
+        (wave admission, DESIGN.md §10), ``host_bytes``, the bytes of
+        shares brought to the host between the coded phases, and
+        ``mesh_bytes``, the bytes of decode-quorum shares copied chip to
+        chip to the decode device.  Backends without such machinery report
+        zeros."""
         return {"waves": 0, "padded_lanes": 0, "deferred_groups": 0,
-                "host_bytes": 0}
+                "host_bytes": 0, "mesh_bytes": 0}
 
     def take_new_liars(self) -> set:
         """Drain liar ids caught since the last call — roster device ids
@@ -192,8 +195,10 @@ class ShardedBackend(MPCBackend):
         return sh
 
     def scheduler_stats(self) -> Dict[str, int]:
-        return {**super().scheduler_stats(), "host_bytes": sum(
-            r.counters["host_bytes"] for r in self._runners.values())}
+        stats = super().scheduler_stats()
+        for k in ("host_bytes", "mesh_bytes"):
+            stats[k] = sum(r.counters[k] for r in self._runners.values())
+        return stats
 
     def run_blocks(self, ops: Sequence[BlockOp]) -> List[BlockResult]:
         outs: List[BlockResult] = []

@@ -43,6 +43,17 @@ def _pad_to(x: np.ndarray, n: int, axis: int = 0) -> np.ndarray:
     return np.pad(x, widths)
 
 
+def _runs(xs):
+    """``(first, last)`` of each run of consecutive integers in sorted ``xs``."""
+    out = []
+    for x in xs:
+        if out and out[-1][1] == x - 1:
+            out[-1] = (out[-1][0], x)
+        else:
+            out.append((x, x))
+    return out
+
+
 def mod_ring_reduce_scatter(x, axis: str, p: int, n_shards: int):
     """Reduce-scatter of field elements with per-hop modular folding.
 
@@ -85,6 +96,9 @@ class ShardedCMPC:
     and laid out worker-major so device d owns workers
     ``d·(N_pad/D) .. (d+1)·(N_pad/D)-1``.  Padded workers have all-zero
     Vandermonde rows: they contribute nothing to the scattered reduction.
+    Phases 1-2 run on the mesh; the I points stay on the chips, and the
+    decode quorum's ``t²+z`` rows go chip to chip to the master's device
+    (:attr:`decode_device`) for the decode.  No share crosses the host.
 
     Optimization knobs (paper-faithful defaults; see EXPERIMENTS.md §Perf):
 
@@ -102,10 +116,11 @@ class ShardedCMPC:
     axis: str = "model"
     wire_dtype: str = "int64"
     prg_masks: bool = False
-    #: bytes through the host, counted by :meth:`run`
+    #: bytes counted by :meth:`run`: through the host (none), and of
+    #: quorum rows copied chip to chip to the decode device
     counters: Dict[str, int] = dataclasses.field(
-        default_factory=lambda: {"host_bytes": 0}, init=False, compare=False,
-        repr=False)
+        default_factory=lambda: {"host_bytes": 0, "mesh_bytes": 0},
+        init=False, compare=False, repr=False)
 
     @classmethod
     def from_spec(cls, spec: MPCSpec, mesh: Mesh, *, axis: str = "model",
@@ -235,23 +250,61 @@ class ShardedCMPC:
         """The compiled step, built once per runner (not per block)."""
         return self.build_step()
 
-    def run(self, a, b, key, *, survivors: Optional[np.ndarray] = None):
-        """Full distributed run (phases 1-2 on mesh, decode on master).
+    @property
+    def decode_device(self):
+        """The master's chip: the mesh's first device, which holds
+        workers ``0 .. N_pad/D-1`` and runs the decode."""
+        return self.mesh.devices.flat[0]
 
-        The I points come to the host and their first N rows go back to
-        the default device for the decode; both transfers add to
-        ``counters["host_bytes"]``."""
+    @cached_property
+    def _quorum_idx(self):
+        """The identity index over the ``t²+z`` gathered quorum rows."""
+        return jnp.arange(self.proto.recovery_threshold)
+
+    def run(self, a, b, key, *, survivors: Optional[np.ndarray] = None):
+        """Full distributed run: phases 1-2 on the mesh, the decode on
+        :attr:`decode_device`.
+
+        Only the decode quorum's ``t²+z`` I-point rows leave the chips
+        that computed them, chip to chip (:meth:`gather_quorum`); nothing
+        crosses the host, and nothing here waits for the device, so the
+        next block dispatches while this one runs."""
         pr = self.proto
+        idx = pr.survivor_prefix(survivors)
         with span("sharded.shares"):
             i_pts = self.shares(a, b, key)
-        with span("sharded.fetch"):
-            host = np.asarray(i_pts)
-            self.counters["host_bytes"] += host.nbytes
-        with span("sharded.upload"):
-            rows = jnp.asarray(host[: pr.n_workers])
-            self.counters["host_bytes"] += rows.nbytes
+        with span("sharded.gather"):
+            rows = self.gather_quorum(i_pts, idx)
         with span("sharded.decode"):
-            return pr.decode(rows, survivors)
+            _, dec_rows = pr.plan.survivor_tables(tuple(idx))
+            return pr.plan.stages().decode(rows, self._quorum_idx, dec_rows)
+
+    def gather_quorum(self, i_pts, idx) -> jnp.ndarray:
+        """The I-point rows ``idx`` (ascending), stacked on
+        :attr:`decode_device`.
+
+        Each chip's contiguous runs of quorum rows are sliced where they
+        live; the slices of other chips are copied to the decode device,
+        and their bytes add to ``counters["mesh_bytes"]``.  Where the
+        worker axis is replicated over other mesh axes, the decode
+        device's own replica is read first."""
+        dev = self.decode_device
+        owner = {}                     # first row of a shard -> its shard
+        for sh in i_pts.addressable_shards:
+            start = sh.index[0].start or 0
+            if start not in owner or sh.device == dev:
+                owner[start] = sh
+        pieces = []
+        for start, sh in sorted(owner.items()):
+            local = [int(r) - start for r in idx
+                     if 0 <= int(r) - start < sh.data.shape[0]]
+            for first, last in _runs(local):
+                rows = sh.data[first: last + 1]
+                if sh.device != dev:
+                    rows = jax.device_put(rows, dev)
+                    self.counters["mesh_bytes"] += rows.nbytes
+                pieces.append(rows)
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
 
     def shares(self, a, b, key):
         """Phases 1-2 on the mesh: the I points ``[N_pad, m/t, m/t]``,

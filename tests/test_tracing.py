@@ -1,5 +1,5 @@
-"""Named scopes on the stage programs and the field GEMM, and the host
-bytes counter.
+"""Named scopes on the stage programs and the field GEMM, and the sharded
+runner's byte counters.
 
 The scopes are metadata of the ops: the op-name paths of the lowered HLO
 must hold each stage's scope and the field GEMM's, and the programs with
@@ -143,8 +143,7 @@ SHARDED = textwrap.dedent(
     want = (a.astype(object).T @ b.astype(object)) % p
     print(json.dumps({"scopes": scopes, "same": strip(scoped) == strip(bare),
                       "exact": bool(np.array_equal(np.asarray(y), want)),
-                      "host_bytes": sh.counters["host_bytes"],
-                      "n_pad": sh.n_pad, "n": proto.n_workers}))
+                      "counters": sh.counters}))
     """
 )
 
@@ -163,5 +162,7 @@ def test_sharded_step_scopes_and_host_bytes():
             "field_gemm.dot"}
     assert want <= set(out["scopes"]), want - set(out["scopes"])
     assert out["same"] and out["exact"]
-    # one block: the I points down, the first N rows back, int64 each
-    assert out["host_bytes"] == (out["n_pad"] + out["n"]) * (8 // 2) ** 2 * 8
+    # one block: nothing through the host; of the quorum's rows 0-5 only
+    # row 5 lies off the first of the four chips (5 workers each), int64
+    assert out["counters"] == {"host_bytes": 0,
+                               "mesh_bytes": 1 * (8 // 2) ** 2 * 8}
