@@ -65,3 +65,16 @@ def collective_ms_per_product(run) -> Optional[float]:
     lo, hi = run.window_ns
     ns = tr.per_device_max(run.trace.ops, run.devices, COLLECTIVE_OPS, lo, hi)
     return ns / 1e6 / run.completed if ns else None
+
+
+def routed_macs(run) -> int:
+    """Multiply-adds of the completed routed requests' products as the
+    plain layer has them: ``Σ n_e·K·F`` over each request's held experts,
+    ``n_e`` the rows routed to expert ``e``; 0 where the run has none."""
+    shapes = run.cell.config["projections"]
+    total = 0
+    for r in run.requests:
+        if r.completed and getattr(r, "tokens", ()):
+            k, f = shapes[r.projection]
+            total += sum(len(rows) for rows in r.tokens) * k * f
+    return total

@@ -8,6 +8,7 @@ at a small size.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 import shutil
 import tempfile
@@ -111,6 +112,37 @@ def make_operands(cell: cells.Cell, pools: Dict[str, int], key):
     return jax.block_until_ready(jax.jit(make)(key))
 
 
+def make_routed_operands(cell: cells.Cell, pools: Dict[str, int], key):
+    """Every operand of a routed run, made on the device in one jitted
+    call: ``{projection: ([activation chunk per request], weights)}``,
+    float32, ``weights[layer, expert]`` the held expert's ``[K, F]``."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, rows = cell.config, cell.traffic["tokens"]
+    layers = cfg["model"]["num_hidden_layers"]
+    experts = cfg["model"]["n_routed_experts"]
+    shapes = tuple((p, pools[p], *cfg["projections"][p])
+                   for p in cell.traffic["projections"])
+    act_std = cfg["operands"]["activation_std"]
+    w_std = cfg["operands"]["weight_std"]
+
+    def make(key):
+        out = {}
+        for i, (name, count, k, f) in enumerate(shapes):
+            ka, kw = jax.random.split(jax.random.fold_in(key, i))
+            acts = act_std * jax.random.normal(ka, (count, rows, k), jnp.float32)
+            keys = jax.vmap(jax.random.fold_in, (None, 0))(
+                kw, jnp.arange(layers * experts))
+            w = jax.vmap(functools.partial(jax.random.normal, shape=(k, f),
+                                           dtype=jnp.float32))(keys)
+            out[name] = ([acts[j] for j in range(count)],
+                         w_std * w.reshape(layers, experts, k, f))
+        return out
+
+    return jax.block_until_ready(jax.jit(make)(key))
+
+
 class Reservoir:
     """A uniform sample, drawn from the seed, of ``size`` requests whose
     results are kept for the check (the others are let go)."""
@@ -173,6 +205,19 @@ def _check(cell: cells.Cell, reqs: List[tr.Request], operands) -> Dict:
     spec = cell.config["spec"]
     f, p = spec["frac_bits"], spec["p"]
     kept = [r for r in reqs if r.completed and r.result is not None]
+    compare = _routed_gap if cell.traffic["kind"] == "routed" else _dense_gap
+    gap, compared = compare(cell, kept, operands, f, p)
+    limits = cell.config["limits"]
+    missing = sum(not r.completed for r in reqs)
+    return {"compared": compared,
+            "checks": {"fixed_point_gap": {"value": gap,
+                                           "limit": limits["fixed_point_gap"]},
+                       "missing": {"value": missing,
+                                   "limit": limits["missing"]}}}
+
+
+def _dense_gap(cell, kept, operands, f: int, p: int):
+    """``(largest gap, products compared)`` of one product a request."""
     gap, compared = 0.0, 0
     by_weight: Dict[tuple, List[tr.Request]] = {}
     for r in kept:
@@ -188,13 +233,23 @@ def _check(cell: cells.Cell, reqs: List[tr.Request], operands) -> Dict:
             gap = max(gap, reference.fixed_point_gap(
                 y, ref[i * rows:(i + 1) * rows], f))
             compared += 1
-    limits = cell.config["limits"]
-    missing = sum(not r.completed for r in reqs)
-    return {"compared": compared,
-            "checks": {"fixed_point_gap": {"value": gap,
-                                           "limit": limits["fixed_point_gap"]},
-                       "missing": {"value": missing,
-                                   "limit": limits["missing"]}}}
+    return gap, compared
+
+
+def _routed_gap(cell, kept, operands, f: int, p: int):
+    """``(largest gap, products compared)`` over every held expert's
+    product of each kept routed request: the chunk's routed rows times
+    that expert's weight."""
+    gap, compared = 0.0, 0
+    for r in kept:
+        acts, weights = operands[r.projection]
+        chunk = np.asarray(acts[r.operand])
+        for e, (rows, y) in enumerate(zip(r.tokens, r.result, strict=True)):
+            w = np.asarray(weights[r.layer, e])
+            ref = reference.exact_product(chunk[rows], w, f, p)
+            gap = max(gap, reference.fixed_point_gap(np.asarray(y), ref, f))
+            compared += 1
+    return gap, compared
 
 
 def end_to_end(name: str, reqs: List[tr.Request], setup_s: float) -> float:
@@ -215,6 +270,28 @@ def end_to_end(name: str, reqs: List[tr.Request], setup_s: float) -> float:
     raise ValueError(f"no end-to-end metric {name!r} in the harness")
 
 
+def routed_warmup(cell: cells.Cell, routings, pools: Dict[str, int]
+                  ) -> List[tr.RoutedRequest]:
+    """Warm-up requests that build every shape a routed window uses: the
+    program builds its tiling per row count and projection shape, so one
+    request a chunk's routing and shape, over the spare activation, with
+    only the experts whose row count is new for that shape."""
+    out, seen = [], set()
+    for p in cell.traffic["projections"]:
+        shape = tuple(cell.config["projections"][p])
+        for chunk in routings:
+            fresh = []
+            for rows in chunk:
+                if (shape, rows.size) not in seen:
+                    seen.add((shape, rows.size))
+                    fresh.append(rows)
+            if fresh:
+                out.append(tr.RoutedRequest(index=-1, projection=p,
+                                            operand=pools[p],
+                                            tokens=tuple(fresh)))
+    return out
+
+
 def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
              t_start: float, compiles: CompileCounter,
              server_factory: Optional[Callable] = None,
@@ -233,12 +310,15 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
     peaks = cells.device_peaks(kind) if require_tpu else {}
     traffic = cell.traffic
     closed = traffic["kind"] == "closed"
+    routed = traffic["kind"] == "routed"
     layers = cell.config["model"]["num_hidden_layers"]
-    schedule = [] if closed else tr.open_schedule(traffic, seed, seconds, layers)
+    schedule = ([] if closed or routed
+                else tr.open_schedule(traffic, seed, seconds, layers))
     pools = tr.pool_sizes(traffic, schedule)     # one more row each: warm-up
     key = device_key(seed)
-    operands = make_operands(cell, {p: n + 1 for p, n in pools.items()},
-                             jax.random.fold_in(key, 0))
+    make = make_routed_operands if routed else make_operands
+    operands = make(cell, {p: n + 1 for p, n in pools.items()},
+                    jax.random.fold_in(key, 0))
     server = (server_factory or (lambda: ProgramServer(
         cell.config, devices, jax.random.fold_in(key, 1))))()
 
@@ -249,8 +329,29 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
     # warm-up: each projection alone, then all of them in one flush
     warm = [tr.Request(index=-1, projection=p, operand=pools[p])
             for p in traffic["projections"]]
-    for batch in [[w] for w in warm] + ([warm] if not closed else []):
-        rids = [server.submit(*pair(w)) for w in batch]
+    batches = [[w] for w in warm] + ([warm] if not closed else [])
+    if routed:
+        routings = tr.chunk_routings(traffic, cell.config)
+        take = jax.jit(lambda chunk, rows, w, layer, e: (chunk[rows],
+                                                         w[layer, e]))
+
+        def experts(req):
+            """One operand pair a held expert, taken on the device: its
+            routed rows of the chunk and its weight."""
+            acts, weights = operands[req.projection]
+            return [take(acts[req.operand], rows, weights, req.layer, e)
+                    for e, rows in enumerate(req.tokens)]
+
+        batches = [[r] for r in routed_warmup(cell, routings, pools)]
+
+    def submit(batch):
+        """The warm-up batch's request ids, its operands made here."""
+        if routed:
+            return [server.submit(a, b) for a, b in experts(batch[0])]
+        return [server.submit(*pair(w)) for w in batch]
+
+    for batch in batches:
+        rids = submit(batch)
         out, failures = server.flush()
         for rid in rids:
             if rid not in out:
@@ -266,7 +367,11 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
     compiles.active = True
     try:
         with spans("window"):
-            if closed:
+            if routed:
+                keep = Reservoir(traffic["check_sample"], seed)
+                reqs = window.routed_loop(server, traffic, layers, routings,
+                                          seed, experts, seconds, spans, keep)
+            elif closed:
                 keep = Reservoir(traffic["check_sample"], seed)
                 reqs = window.closed_loop(server, traffic, layers, pair,
                                           seconds, spans, keep)
@@ -306,7 +411,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool, *,
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": end_to_end(m["name"], reqs, setup_s),
                                   "unit": m["unit"]}
-    late = [r.sent_s - r.due_s for r in reqs if not closed]
+    late = [r.sent_s - r.due_s for r in reqs if not (closed or routed)]
     result = {
         "correct": (check["compared"] > 0 and all(
             c["value"] <= c["limit"] for c in check["checks"].values())),

@@ -14,6 +14,20 @@ A mix file (``bench/traffic/<name>.json``) gives
 * open loops: ``rate_rps``, which a cell may set in
   ``bench/cells/<workload>.json``.
 
+A ``"routed"`` mix is a closed loop over one chip's share of a
+routed-expert layer: one request is one projection applied to a prefill
+chunk of ``tokens`` rows, as one product a held expert, each over the
+rows the router sent that expert, served in one flush.  It reads
+``tokens``, ``projections``, ``operand_pool`` and ``check_sample``; the
+expert counts come from the configuration (:func:`chunk_routings`).  The
+routing has no free parameter: each token picks ``top_k`` distinct
+experts of all those routed over, every choice alike, the balance that
+an MoE's load-balancing losses train toward.  Each chunk of the pool has
+its own routing, so loads differ from request to request; the draws are
+fixed by the mix and the configuration alone, so every seed routes the
+same loads (the program compiles its eager tiling per row count, and the
+warm-up builds each one), and the seed relabels which tokens they are.
+
 A key the generator does not read is an error (:func:`validate`), so a
 mix cannot ask for something it silently does not get.
 
@@ -30,7 +44,7 @@ projections, and only their order differs.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -38,6 +52,7 @@ import numpy as np
 KEYS = {
     "closed": {"kind", "rows", "projections", "operand_pool", "check_sample"},
     "open": {"kind", "rows", "projections", "rate_rps"},
+    "routed": {"kind", "tokens", "projections", "operand_pool", "check_sample"},
 }
 
 
@@ -58,6 +73,15 @@ class Request:
     @property
     def completed(self) -> bool:
         return not self.failure and np.isfinite(self.done_s)
+
+
+@dataclasses.dataclass
+class RoutedRequest(Request):
+    """One projection of a routed layer over the held experts: ``tokens``
+    gives, for each held expert in turn, the rows of the chunk routed to
+    it; ``result`` is then the list of their products."""
+
+    tokens: Tuple[np.ndarray, ...] = ()
 
 
 def rng_for(seed: int) -> np.random.Generator:
@@ -110,10 +134,63 @@ def open_schedule(traffic: dict, seed: int, seconds: float, layers: int
     return out
 
 
+def balanced_routing(tokens: int, experts: int, top_k: int,
+                     rng: np.random.Generator) -> np.ndarray:
+    """``[tokens, top_k]``: each token's ``top_k`` distinct experts of
+    ``experts``, every choice alike."""
+    return np.argsort(rng.random((tokens, experts)), axis=1)[:, :top_k]
+
+
+def held_experts(experts: int, held: int, offset: int = 0) -> np.ndarray:
+    """The experts that the chip at ``offset`` holds when ``experts`` lie
+    over ``experts // held`` chips: ``offset`` and every ``chips``-th
+    expert after it."""
+    chips = experts // held
+    if chips * held != experts or not 0 <= offset < chips:
+        raise ValueError(f"{experts} experts do not lie {held} a chip, "
+                         f"or offset {offset} names no chip")
+    return offset + chips * np.arange(held)
+
+
+def chunk_routings(traffic: dict, config: dict
+                   ) -> List[Tuple[np.ndarray, ...]]:
+    """For each chunk of the pool, the rows (token indices, ascending)
+    routed to each expert the chip holds: of the configuration's
+    ``published.n_routed_experts`` experts, each token routed to
+    ``model.num_experts_per_tok`` (:func:`balanced_routing`), the chip
+    holds ``model.n_routed_experts``.  Chunk ``j``'s draw depends on the
+    mix's and the configuration's sizes and ``j`` alone."""
+    model = config["model"]
+    tokens, top_k = traffic["tokens"], model["num_experts_per_tok"]
+    experts = config["published"]["n_routed_experts"]
+    held = held_experts(experts, model["n_routed_experts"])
+    out = []
+    for j in range(traffic["operand_pool"]):
+        picks = balanced_routing(tokens, experts, top_k, np.random.default_rng(
+            [tokens, experts, top_k, j]))
+        out.append(tuple(np.flatnonzero((picks == e).any(axis=1)).astype(np.int32)
+                         for e in held))
+    return out
+
+
+def routed_request(traffic: dict, index: int, layers: int, routings,
+                   seed: int) -> RoutedRequest:
+    """The ``index``-th request of a routed loop: projection, chunk and
+    layer as :func:`closed_request` has them, the chunk's routing with its
+    tokens relabelled by a permutation drawn from the seed and the chunk."""
+    base = closed_request(traffic, index, layers)
+    perm = np.random.default_rng([abs(int(seed)), base.operand]).permutation(
+        traffic["tokens"]).astype(np.int32)
+    return RoutedRequest(index=index, projection=base.projection,
+                         operand=base.operand, layer=base.layer,
+                         tokens=tuple(np.sort(perm[rows])
+                                      for rows in routings[base.operand]))
+
+
 def pool_sizes(traffic: dict, schedule: List[Request]) -> Dict[str, int]:
-    """Activations each projection needs: the closed loop's pool, or one
-    per open-loop request of that projection."""
-    if traffic["kind"] == "closed":
+    """Activations each projection needs: the closed (or routed) loop's
+    pool, or one per open-loop request of that projection."""
+    if traffic["kind"] in ("closed", "routed"):
         return {p: traffic["operand_pool"] for p in traffic["projections"]}
     sizes = {p: 0 for p in traffic["projections"]}
     for r in schedule:

@@ -1,4 +1,4 @@
-"""The measured window: a closed or an open loop over one server.
+"""The measured window: a closed, a routed or an open loop over one server.
 
 Every request is timed on the host's monotonic clock.  A request
 completes when its decoded result is ready on the device
@@ -69,6 +69,37 @@ def closed_loop(server, traffic: dict, layers: int, operands: Callable,
             keep(req, out[rid])
         else:
             req.failure = failures.get(rid, "no result")
+        reqs.append(req)
+    return reqs
+
+
+def routed_loop(server, traffic: dict, layers: int, routings, seed: int,
+                operands: Callable, seconds: float, spans: Spans,
+                keep: Callable) -> List[tr.Request]:
+    """One client over a routed layer: each request submits one product a
+    held expert (``operands(req)`` gives their operand pairs), flushes
+    once, and completes when the last product is ready.  Sends until
+    ``seconds`` have passed, then completes the request in flight.
+    ``keep(req, results)`` decides which requests stay for the check."""
+    reqs: List[tr.Request] = []
+    t0 = time.perf_counter()
+    while not reqs or time.perf_counter() - t0 < seconds:
+        req = tr.routed_request(traffic, len(reqs), layers, routings, seed)
+        pairs = operands(req)
+        req.sent_s = time.perf_counter() - t0
+        with spans("submit"):
+            rids = [server.submit(a, b) for a, b in pairs]
+        with spans("flush"):
+            out, failures = server.flush()
+        lost = [rid for rid in rids if rid not in out]
+        if lost:
+            req.failure = failures.get(lost[0], "no result")
+        else:
+            ys = [out[rid] for rid in rids]
+            with spans("wait"):
+                _ready(ys)
+            req.done_s = time.perf_counter() - t0
+            keep(req, ys)
         reqs.append(req)
     return reqs
 

@@ -1,8 +1,10 @@
 """sharded.host_mib_per_product: MiB that cross between the host and the
-chips per completed product in the sharded runner: each block's I points
-brought to the host and their first N rows sent back to the decode's chip.
-Read from the program's ``host_bytes`` counter (``ShardedCMPC.run``, via
-``MPCSession.stats``); ``None`` where the program has no such counter."""
+chips per completed product in the sharded runner.  Since the runner keeps
+each block's I points on the chips and copies only the decode quorum's
+rows chip to chip (``ShardedCMPC.gather_quorum``), this reads 0; a share
+brought back through the host would show here.  Read from the program's
+``host_bytes`` counter (``ShardedCMPC.run``, via ``MPCSession.stats``);
+``None`` where the program has no such counter."""
 
 
 def read(run):

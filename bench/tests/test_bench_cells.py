@@ -1,6 +1,8 @@
 """Every cell of BENCHMARK.json resolves its files by name, and the
 benchmark's data keeps to the shape the harness reads."""
+import ast
 import json
+import operator
 import sys
 from pathlib import Path
 
@@ -31,6 +33,13 @@ def test_cell_resolves_config_traffic_and_metrics(workload):
         assert m["moves"] in names
     if cell.traffic["kind"] == "open":
         assert cell.traffic["rate_rps"] > 0
+    if cell.traffic["kind"] == "routed":
+        from bench.harness import traffic
+
+        routings = traffic.chunk_routings(cell.traffic, cell.config)
+        assert len(routings) == cell.traffic["operand_pool"]
+        assert {len(c) for c in routings} == {
+            cell.config["model"]["n_routed_experts"]}
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
@@ -56,14 +65,53 @@ def test_config_spec_gives_the_stated_workers(path):
     assert spec.n_workers == cfg["n_workers"]
 
 
+#: what a side of ``projection_widths`` may join its top-level keys with
+WIDTH_OPS = {ast.Mult: operator.mul, ast.FloorDiv: operator.floordiv,
+             ast.Add: operator.add}
+
+
+def _width(expr: str, cfg: dict) -> int:
+    """A side of a projection as ``expr`` gives it: the configuration's
+    top-level keys and whole numbers joined by ``*``, ``//`` and ``+``."""
+    def value(node):
+        if isinstance(node, ast.Name):
+            return cfg[node.id]
+        if isinstance(node, ast.Constant) and type(node.value) is int:
+            return node.value
+        if isinstance(node, ast.BinOp) and type(node.op) in WIDTH_OPS:
+            return WIDTH_OPS[type(node.op)](value(node.left), value(node.right))
+        raise ValueError(f"{expr!r}: only top-level keys, *, // and +")
+
+    return value(ast.parse(expr, mode="eval").body)
+
+
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
 def test_config_keeps_the_published_widths_and_depth(path):
+    """The model's published ``config.json`` keys sit at the file's top
+    level, a cut at the value run and its published value in
+    ``published``; the ``model`` group that the harness reads and every
+    projection's ``[K, F]`` (``projection_widths``, each side a formula of
+    top-level keys) agree with them."""
     cfg = json.loads(path.read_text())
-    model = cfg["model"]
-    assert (model["hidden_size"], model["intermediate_size"]) == (2048, 8192)
-    assert model["num_hidden_layers"] == 40
-    assert cfg["projections"]["down"] == [8192, 2048]
+    assert {k: cfg[k] for k in cfg["model"]} == cfg["model"]
+    assert set(cfg["projection_widths"]) == set(cfg["projections"])
+    for name, sides in cfg["projection_widths"].items():
+        assert [_width(s, cfg) for s in sides] == cfg["projections"][name]
     assert set(cfg["reduced"]) == set(cfg["published"])
+    for key in cfg["reduced"]:
+        assert cfg[key] < cfg["published"][key]
+
+
+def test_a_width_formula_reads_only_top_level_keys():
+    cfg = {"hidden_size": 2048, "num_attention_heads": 32,
+           "num_key_value_heads": 8}
+    assert _width("num_key_value_heads * (hidden_size // num_attention_heads)",
+                  cfg) == 512
+    for bad in ("hidden_size - 1", "hidden_size ** 2", "len(hidden_size)"):
+        with pytest.raises(ValueError):
+            _width(bad, cfg)
+    with pytest.raises(KeyError):
+        _width("head_dim", cfg)
 
 
 @pytest.mark.parametrize("path", sorted((ROOT / "bench" / "traffic").glob("*.json")),

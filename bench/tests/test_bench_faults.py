@@ -26,6 +26,7 @@ TINY = {
     "local.decode": ("tiny.age-s2t2z2.local", "decode-poisson"),
     "batched.decode": ("tiny.age-s2t4z4.batched", "decode-poisson"),
     "sharded.prefill": ("tiny.age-s2t2z2.sharded4", "prefill-mlp"),
+    "local.routed": ("tiny-moe.age-s2t2z2.local", "prefill-routed"),
 }
 
 
@@ -51,7 +52,7 @@ def run(name: str, compiles, server_factory=None) -> dict:
 
 
 @pytest.mark.parametrize("name", ["local.prefill", "local.decode",
-                                  "batched.decode"])
+                                  "batched.decode", "local.routed"])
 def test_sound_run_is_correct(compiles, name):
     res = run(name, compiles)
     assert res["correct"], res["checks"]
@@ -59,7 +60,8 @@ def test_sound_run_is_correct(compiles, name):
     assert res["window"]["compiles"] == 0
 
 
-@pytest.mark.parametrize("name", ["local.prefill", "local.decode"])
+@pytest.mark.parametrize("name", ["local.prefill", "local.decode",
+                                  "local.routed"])
 def test_control_is_not_correct(compiles, name):
     from bench.harness.server import ControlServer
 
@@ -69,7 +71,8 @@ def test_control_is_not_correct(compiles, name):
 
 
 @pytest.mark.parametrize("name,backend", [("local.prefill", "LocalBackend"),
-                                          ("batched.decode", "BatchedBackend")])
+                                          ("batched.decode", "BatchedBackend"),
+                                          ("local.routed", "LocalBackend")])
 def test_altered_answer_is_not_correct(compiles, monkeypatch, name, backend):
     from repro.mpc import backends
 
@@ -104,6 +107,41 @@ def test_half_the_batch_left_out_is_not_correct(compiles, monkeypatch):
     res = run("batched.decode", compiles)
     assert not res["correct"]
     assert res["checks"]["missing"]["value"] > 0
+
+
+def test_expert_products_left_out_are_not_correct(compiles, monkeypatch):
+    """Half of a routed flush's blocks fail in the window: the requests
+    they belong to never complete."""
+    from repro.mpc import backends
+    from repro.mpc.api import BlockFailure
+
+    real = backends.LocalBackend.run_blocks
+
+    def half(self, ops):
+        outs = real(self, ops)
+        if not compiles.active:        # the warm-up runs sound
+            return outs
+        return [o if i % 2 else BlockFailure("left out")
+                for i, o in enumerate(outs)]
+
+    monkeypatch.setattr(backends.LocalBackend, "run_blocks", half)
+    res = run("local.routed", compiles)
+    assert not res["correct"]
+    assert res["checks"]["missing"]["value"] > 0
+
+
+def test_routed_check_compares_every_expert(compiles):
+    """A sampled routed request is compared product by product, one a
+    held expert, on the rows routed to it."""
+    held = []
+    res = runner.run_cell(tiny_cell("local.routed"), 2**33 + 1, 0.3, False,
+                          t_start=time.perf_counter(), compiles=compiles,
+                          require_tpu=False, on_requests=held.extend)
+    kept = [r for r in held if r.result is not None]
+    assert res["correct"] and res["window"]["compiles"] == 0
+    assert res["window"]["compared"] == sum(len(r.tokens) for r in kept) > 0
+    for r in kept:
+        assert [y.shape[0] for y in r.result] == [len(t) for t in r.tokens]
 
 
 SHARDED = r"""

@@ -202,7 +202,7 @@ rng = np.random.default_rng(0)
 pairs = [(rng.standard_normal((8, 16)), rng.standard_normal((16, 8)))
          for _ in range(2)]
 sess.submit(*pairs[0]); sess.flush()              # compiled before tracing
-before = sess.stats["host_bytes"]
+before = {{k: sess.stats[k] for k in ("host_bytes", "mesh_bytes")}}
 log_dir = tempfile.mkdtemp()
 jax.profiler.start_trace(log_dir)
 rids = [sess.submit(a, b) for a, b in pairs]
@@ -210,14 +210,13 @@ out = sess.flush()
 jax.block_until_ready(list(out.values()))
 jax.profiler.stop_trace()
 pt = scopes.load(log_dir)
-sh = next(iter(sess.backend._runners.values()))
 print(json.dumps({{
     "rids": rids,
     "spans": [[name, ids] for (name, _, _), ids in zip(pt.spans, pt.ids)],
     "bench_host": tr.load(log_dir).host,
-    "host_bytes": sess.stats["host_bytes"] - before,
-    "blocks": 2 * 2, "n_pad": sh.n_pad, "n": sh.proto.n_workers,
-    "mt": m // 2}}))
+    "host_bytes": sess.stats["host_bytes"] - before["host_bytes"],
+    "mesh_bytes": sess.stats["mesh_bytes"] - before["mesh_bytes"],
+    "blocks": 2 * 2, "mt": m // 2}}))
 """
 
 
@@ -236,13 +235,14 @@ def test_profiled_flush_on_four_devices():
         assert ["mpc.session.assemble", {"rid": rid}] in spans
     for name in ("mpc.session.encode", "mpc.session.tile", "mpc.session.flush",
                  "mpc.backend.run_blocks", "mpc.sharded.shares",
-                 "mpc.sharded.fetch", "mpc.sharded.upload",
-                 "mpc.sharded.decode"):
+                 "mpc.sharded.gather", "mpc.sharded.decode"):
         assert name in names, name
-    # one fetch and one block span per block, numbered within the flush
-    assert names.count("mpc.sharded.fetch") == res["blocks"]
+    # one gather and one block span per block, numbered within the flush
+    assert names.count("mpc.sharded.gather") == res["blocks"]
     assert sorted(ids["block"] for name, ids in spans
                   if name == "mpc.block") == list(range(res["blocks"]))
     assert res["bench_host"] == []      # trace.load keeps bench.* alone
-    assert res["host_bytes"] == (res["blocks"] * (res["n_pad"] + res["n"])
-                                 * res["mt"] ** 2 * 8)
+    # no share crosses the host; of the decode quorum's t²+z = 6 rows the
+    # default survivors leave one off the decode chip (workers 5 a chip)
+    assert res["host_bytes"] == 0
+    assert res["mesh_bytes"] == res["blocks"] * res["mt"] ** 2 * 8

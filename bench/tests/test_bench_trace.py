@@ -94,3 +94,45 @@ def test_collective_time_per_product_on_the_busiest_chip():
     quiet = tr.Trace(ops={0: [("fusion.1", 0, 10)], 1: [("copy.2", 0, 10)]},
                      modules={}, host=[("bench.window", 0, 10)])
     assert read(_view(quiet, [0, 1])) is None               # none in the trace
+
+
+ROUTED_CELL = "v2lite-experts-local.prefill-routed"
+
+
+def _routed_view(trace, loads, blocks, **kw):
+    """Two completed routed requests of the routed cell, gate then down,
+    each with the held loads ``loads``."""
+    cell = cells.resolve(BENCH, ROUTED_CELL)
+    reqs = [traffic.RoutedRequest(index=i, projection=p, operand=0,
+                                  sent_s=0.0, done_s=1.0,
+                                  tokens=tuple(range(n) for n in loads))
+            for i, p in enumerate(("expert_gate", "expert_down"))]
+    return _view(trace, [0], cell=cell, requests=reqs, blocks=blocks, **kw)
+
+
+def test_routed_pad_share_from_the_block_sides():
+    # a chunk of the cell's routing: each held expert's product tiles to
+    # 48 m = 256 blocks a projection
+    loads = [199, 197, 201, 197, 182, 182, 191, 197]
+    blocks = {(17, 2, 2, 2, 256): 2 * 8 * 48}
+    view = _routed_view(SMALL, loads, blocks)
+    work = 2 * sum(loads) * 2048 * 1408
+    volume = 768 * 256 ** 3
+    assert readers.routed_macs(view) == work
+    share = cells.metric_reader("routed.pad_share")(view)
+    assert share == pytest.approx(100 * (1 - work / volume))
+    assert 30.5 < share < 31
+    assert cells.metric_reader("routed.pad_share")(
+        _routed_view(SMALL, loads, {})) is None            # no blocks
+    # the dense cells' requests carry no routing
+    assert readers.routed_macs(_view(SMALL, [0])) == 0
+
+
+def test_step_mfu_over_the_window_and_the_peak():
+    peaks = cells.device_peaks("TPU v5 lite")
+    view = _routed_view(SMALL, [4, 2], {(17, 2, 2, 2, 256): 4}, peaks=peaks)
+    read = cells.metric_reader("step_mfu.routed")
+    ops = 2 * (6 * 2048 * 1408 + 6 * 1408 * 2048) * 16
+    assert read(view) == pytest.approx(100 * ops / (1000e-9 * 393e12))
+    assert read(_routed_view(None, [4, 2], {}, peaks=peaks)) is None
+    assert read(_routed_view(SMALL, [4, 2], {})) is None   # no peaks known

@@ -118,3 +118,103 @@ def test_end_to_end_metrics_from_requests():
             np.percentile(lat, q))
     with pytest.raises(ValueError):
         end_to_end("tokens_per_s", reqs, 0)
+
+
+ROUTED = {"kind": "routed", "tokens": 2048,
+          "projections": ["expert_gate", "expert_up", "expert_down"],
+          "operand_pool": 4, "check_sample": 3}
+#: the expert counts a routed mix takes from its configuration
+MOE = {"model": {"n_routed_experts": 8, "num_experts_per_tok": 6},
+       "published": {"n_routed_experts": 64}}
+
+
+def _routing(seed, n=6):
+    routings = tr.chunk_routings(ROUTED, MOE)
+    return [tr.routed_request(ROUTED, i, 26, routings, seed) for i in range(n)]
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 11, 2**40 + 3])
+def test_same_seed_same_routing(seed):
+    a, b = _routing(seed), _routing(seed)
+    for x, y in zip(a, b, strict=True):
+        assert (x.projection, x.operand, x.layer) == (y.projection, y.operand,
+                                                      y.layer)
+        assert all(np.array_equal(p, q)
+                   for p, q in zip(x.tokens, y.tokens, strict=True))
+
+
+def test_every_seed_routes_the_same_held_loads():
+    """Each chunk's loads are fixed by the mix and the configuration; the
+    seed relabels the tokens, so the rows differ and the sizes do not."""
+    want = [[len(rows) for rows in chunk]
+            for chunk in tr.chunk_routings(ROUTED, MOE)]
+    assert len({tuple(w) for w in want}) == len(want)   # chunks differ
+    rows_of = {}
+    for seed in (1, 2, 2**33 + 5, 2**45 + 9):
+        for r in _routing(seed, n=12):
+            assert [len(t) for t in r.tokens] == want[r.operand]
+            for rows in r.tokens:     # distinct tokens of the chunk, in order
+                assert np.all(np.diff(rows) > 0)
+                assert 0 <= rows[0] and rows[-1] < ROUTED["tokens"]
+            rows_of.setdefault(r.operand, set()).add(
+                tuple(r.tokens[0].tolist()))
+    assert all(len(seen) == 4 for seen in rows_of.values())
+
+
+def test_routed_requests_alternate_and_cycle_like_the_closed_loop():
+    reqs = _routing(3, n=9)
+    assert [r.projection for r in reqs] == ROUTED["projections"] * 3
+    assert [r.operand for r in reqs] == [0] * 3 + [1] * 3 + [2] * 3
+    # the three projections of one chunk route the same rows
+    for i in range(0, 9, 3):
+        assert all(np.array_equal(p, q) for p, q in zip(
+            reqs[i].tokens, reqs[i + 2].tokens, strict=True))
+    assert tr.pool_sizes(ROUTED, []) == {p: 4 for p in ROUTED["projections"]}
+
+
+def test_balanced_routing_gives_each_token_top_k_experts_and_the_chips_partition_them():
+    picks = tr.balanced_routing(2048, 64, 6, np.random.default_rng(5))
+    assert picks.shape == (2048, 6)
+    assert all(len(set(row)) == 6 for row in picks.tolist())
+    loads = np.bincount(picks.ravel(), minlength=64)
+    assert loads.sum() == 2048 * 6
+    assert abs(loads.mean() - 192) < 1e-9 and loads.max() < 256
+    held = np.concatenate([tr.held_experts(64, 8, o) for o in range(8)])
+    assert sorted(held) == list(range(64))
+    assert tr.held_experts(64, 8).tolist() == [0, 8, 16, 24, 32, 40, 48, 56]
+    with pytest.raises(ValueError):
+        tr.held_experts(64, 7)
+    # the chip's rows are the tokens that picked its experts
+    chunk = tr.chunk_routings(ROUTED, MOE)[0]
+    picks = tr.balanced_routing(2048, 64, 6, np.random.default_rng(
+        [2048, 64, 6, 0]))
+    for e, rows in zip(tr.held_experts(64, 8), chunk, strict=True):
+        assert np.array_equal(rows, np.flatnonzero((picks == e).any(axis=1)))
+
+
+def test_the_block_sides_follow_the_loads():
+    """Every held expert's product tiles to m = 256 blocks, and the pad
+    share differs from chunk to chunk."""
+    from repro.mpc.tiling import choose_block, padded_volume
+
+    shares = set()
+    for chunk in tr.chunk_routings(ROUTED, MOE):
+        work = volume = 0
+        for rows in chunk:
+            for k, f in ((2048, 1408), (1408, 2048)):
+                m = choose_block(2, 2, rows.size, k, f)
+                assert m == 256
+                work += rows.size * k * f
+                volume += padded_volume(m, rows.size, k, f)
+        shares.add(round(1 - work / volume, 6))
+    assert len(shares) == 4 and all(0.25 < s < 0.4 for s in shares)
+
+
+@pytest.mark.parametrize("change", [{"rows": 2048}, {"rate_rps": 1.0},
+                                    {"experts": 8}, {"load_skew": 0.25},
+                                    {"tokens": None}])
+def test_a_key_the_routed_generator_does_not_read_is_an_error(change):
+    mix = {k: v for k, v in {**ROUTED, **change}.items() if v is not None}
+    with pytest.raises(ValueError):
+        tr.validate(mix)
+    assert tr.validate(ROUTED) is ROUTED
